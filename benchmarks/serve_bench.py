@@ -70,7 +70,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.runtime.server import Request as WaveRequest, Server
 from repro.serving import (ContinuousBatchingEngine, Request, SamplingParams,
-                           ServingMetrics)
+                           ServingMetrics, init_params)
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -446,9 +446,12 @@ def bench_cluster_one(arch_name, args, trace, n_replicas):
         srv.close()
 
 
-def bench_cluster(arch_name, args, mesh):
+def bench_cluster(arch_name, args):
     """The cluster section, two sub-measurements on grouped shared-prefix
-    traces:
+    traces.  The clusters run first and the single-process engine last:
+    a worker opens its own device, which it cannot do while this process
+    holds it, so this process touches jax only after every worker has
+    exited.
 
     * **affinity** (base arrival rate): a 2-replica cluster vs a
       single-process engine on the identical trace — the prefix hit-rate
@@ -479,29 +482,8 @@ def bench_cluster(arch_name, args, mesh):
         "prefix_len": args.prefix_len, "groups": groups,
         "prompt_lens": sorted({len(p) for _, p, _ in trace})}}
 
-    params = T.init_lm(jax.random.PRNGKey(0), arch)
-    ref = bench_continuous(arch, params, mesh, trace, slots=args.slots,
-                           max_len=args.max_len, block_size=args.block_size,
-                           prefill_chunk=args.prefill_chunk,
-                           share_prefix=True, sanitize=args.sanitize)
-    row["single_process"] = ref
-    print(f"[{arch.name}/cluster/single-process] "
-          f"{ref['tokens_per_sec']:.1f} tok/s "
-          f"ttft {_ms(ref['ttft_mean_s'])} "
-          f"hit_rate {ref['prefix_hit_rate']:.2f}")
-
     aff = bench_cluster_one(arch_name, args, trace, n)
     row["affinity"] = aff
-    row["hit_rate_delta_vs_single_process"] = (
-        aff["prefix_hit_rate"] - ref["prefix_hit_rate"])
-    print(f"[{arch.name}/cluster/affinity] {n} replicas "
-          f"{aff['tokens_per_sec']:.1f} tok/s "
-          f"ttft {_ms(aff['ttft_mean_s'])} "
-          f"hit_rate {aff['prefix_hit_rate']:.2f} "
-          f"(delta vs single-process "
-          f"{row['hit_rate_delta_vs_single_process']:+.3f}) "
-          f"split {aff['per_replica_completed']}")
-
     sat = {}
     for nr in (1, n):
         r = bench_cluster_one(arch_name, args, trace_sat, nr)
@@ -513,6 +495,28 @@ def bench_cluster(arch_name, args, mesh):
               f"split {r['per_replica_completed']}")
     row["scaling_tokens_per_sec"] = (sat[n]["tokens_per_sec"]
                                      / sat[1]["tokens_per_sec"])
+
+    mesh = make_host_mesh()
+    params = init_params(arch, mesh, jax.random.PRNGKey(0),
+                         slots=args.slots, max_len=args.max_len)
+    ref = bench_continuous(arch, params, mesh, trace, slots=args.slots,
+                           max_len=args.max_len, block_size=args.block_size,
+                           prefill_chunk=args.prefill_chunk,
+                           share_prefix=True, sanitize=args.sanitize)
+    row["single_process"] = ref
+    print(f"[{arch.name}/cluster/single-process] "
+          f"{ref['tokens_per_sec']:.1f} tok/s "
+          f"ttft {_ms(ref['ttft_mean_s'])} "
+          f"hit_rate {ref['prefix_hit_rate']:.2f}")
+    row["hit_rate_delta_vs_single_process"] = (
+        aff["prefix_hit_rate"] - ref["prefix_hit_rate"])
+    print(f"[{arch.name}/cluster/affinity] {n} replicas "
+          f"{aff['tokens_per_sec']:.1f} tok/s "
+          f"ttft {_ms(aff['ttft_mean_s'])} "
+          f"hit_rate {aff['prefix_hit_rate']:.2f} "
+          f"(delta vs single-process "
+          f"{row['hit_rate_delta_vs_single_process']:+.3f}) "
+          f"split {aff['per_replica_completed']}")
     print(f"[{arch.name}/cluster] {n}-replica scaling "
           f"{row['scaling_tokens_per_sec']:.2f}x on {os.cpu_count()} cores, "
           f"hit-rate delta vs single-process "
@@ -556,16 +560,16 @@ def main():
                          "keep it off for recorded numbers)")
     args = ap.parse_args()
 
-    mesh = make_host_mesh()
     results = {"archs": {}}
+    if not args.no_cluster:            # first: see bench_cluster
+        results["cluster"] = bench_cluster(args.cluster_arch, args)
+    mesh = make_host_mesh()
     for arch_name in (s.strip() for s in args.archs.split(",")):
         results["archs"][arch_name] = bench_arch(arch_name, args, mesh)
     results["prefix_sharing"] = bench_prefix_sharing(args.prefix_arch, args,
                                                      mesh)
     results["sampled_decode"] = bench_sampled_decode(args.prefix_arch, args,
                                                      mesh)
-    if not args.no_cluster:
-        results["cluster"] = bench_cluster(args.cluster_arch, args, mesh)
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2)
     print(f"-> {args.out}")

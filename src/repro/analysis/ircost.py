@@ -22,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jcore
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeSpec
@@ -243,9 +244,9 @@ def _walk_jaxpr(jaxpr, prims: set):
 
 
 def _iter_subjaxprs(value):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jcore.Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

@@ -31,7 +31,8 @@ def get_arch(name: str) -> ArchConfig:
 
 def reduce_for_smoke(arch: ArchConfig) -> ArchConfig:
     """Reduced same-family config for CPU smoke tests: small width, few
-    layers/experts, tiny vocab — structure preserved."""
+    layers/experts, tiny vocab — structure preserved.  ``cut_depth`` is
+    the cut for a chip: published widths, fewer layers."""
     pattern = tuple(Segment(s.blocks, min(s.repeat, 2)) for s in arch.pattern)
     kw = dict(
         name=arch.name + "-smoke",
@@ -64,6 +65,23 @@ def reduce_for_smoke(arch: ArchConfig) -> ArchConfig:
     return dataclasses.replace(arch, **kw)
 
 
-__all__ = ["ARCHS", "get_arch", "reduce_for_smoke", "SHAPES", "ShapeSpec",
+def cut_depth(arch: ArchConfig, n_layers: int) -> ArchConfig:
+    """The same model with fewer layers: every width and dtype kept, each
+    segment's repeat count cut by n_layers / arch.n_layers, whole periods
+    only.  The name says what was cut (``qwen3-8b-8of36L``)."""
+    pattern = []
+    for s in arch.pattern:
+        repeat, rem = divmod(s.repeat * n_layers, arch.n_layers)
+        if rem or repeat < 1:
+            raise ValueError(f"{arch.name}: {n_layers} of {arch.n_layers} "
+                             f"layers does not cut {s} into whole periods")
+        pattern.append(Segment(s.blocks, repeat))
+    return dataclasses.replace(
+        arch, name=f"{arch.name}-{n_layers}of{arch.n_layers}L",
+        n_layers=n_layers, pattern=tuple(pattern))
+
+
+__all__ = ["ARCHS", "get_arch", "reduce_for_smoke", "cut_depth", "SHAPES",
+           "ShapeSpec",
            "ArchConfig", "Segment", "MoESpec", "SSMSpec", "MLASpec",
            "EncoderSpec", "shape_applicable"]

@@ -70,11 +70,11 @@ OPT_PRESETS = {
 
 
 class AdaptiveScheduler:
-    def __init__(self, hw: HW.HardwareProfile = HW.TPU_V5E, *,
+    def __init__(self, hw: Optional[HW.HardwareProfile] = None, *,
                  faithful: bool = True, remat: str = "selective",
                  mem_limit_fraction: float = 0.9, opt_preset: str = "adamw32",
                  seq_sharded: bool = False, moe_ep: bool = False):
-        self.hw = hw
+        self.hw = hw or HW.local_profile()
         self.faithful = faithful
         self.remat = remat
         self.seq_sharded = seq_sharded

@@ -32,6 +32,27 @@ TPU_V5E = HardwareProfile(
     matmul_efficiency=0.6,
 )
 
+# jax's ``device_kind`` of each chip this repo has a profile for
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def profile_for_device_kind(kind: str) -> HardwareProfile:
+    if kind not in DEVICE_KINDS:
+        raise ValueError(f"no hardware profile for device_kind {kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]
+
+
+def local_profile() -> HardwareProfile:
+    """The attached TPU's profile, looked up by ``device_kind``.  Off a TPU
+    (CPU tests, the dry run's virtual devices) plans target TPU_V5E, the
+    deployment chip."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return TPU_V5E
+    return profile_for_device_kind(jax.devices()[0].device_kind)
+
+
 V100_CLUSTER = HardwareProfile(
     name="v100_nvlink",
     peak_flops=125e12,         # fp16 tensor core

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -85,7 +87,7 @@ def flash_attention(q, k, v, *, scale: float | None = None,
     T = k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
 
     bq, bk = min(block_q, S), min(block_k, T)
     pad_q, pad_k = (-S) % bq, (-T) % bk

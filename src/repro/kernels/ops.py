@@ -1,7 +1,7 @@
 """Jit'd wrappers exposing the Pallas kernels with model-layer layouts.
 
 The model passes (B, S, H, D) tensors; the kernels want head-major layouts.
-On non-TPU backends the kernels run in interpret mode (CPU validation); the
+On the CPU the kernels run in interpret mode (validation); the
 production TPU path drops the same calls onto the MXU.
 """
 from __future__ import annotations

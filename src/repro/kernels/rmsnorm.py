@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 DEFAULT_BLOCK_ROWS = 256
 
 
@@ -22,7 +24,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6,
             interpret: bool | None = None):
     """x: (..., D); scale: (D,)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     orig_shape = x.shape
     D = x.shape[-1]
     x2 = x.reshape(-1, D)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 DEFAULT_CHUNK = 128
 
 
@@ -34,30 +36,45 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, h0_ref,
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
     Bm = b_ref[0, 0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)         # (Q, N)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
-    a = a_ref[0, 0].astype(jnp.float32)          # (Q,)
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, Q) row
+    a = a_ref[0, 0].astype(jnp.float32)          # (1, Q) row
 
-    cum = jnp.cumsum(a)                          # (Q,)
-    # intra-chunk: scores[i,j] = (C_i . B_j) dt_j exp(cum_i - cum_j), j <= i
-    seg = cum[:, None] - cum[None, :]
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(iota_i >= iota_j, jnp.exp(seg), 0.0)
+    causal = iota_i >= iota_j
+    eye = iota_i == iota_j
+
+    # Rows and columns swap by a masked sum over the diagonal, and the
+    # prefix sum is a masked sum under the causal mask: exact f32 on the
+    # vector unit, with no transpose or cumsum for Mosaic to lower.
+    def col(row):                                # (1, Q) -> (Q, 1)
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    def row(col):                                # (Q, 1) -> (1, Q)
+        return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+    cum_c = jnp.sum(jnp.where(causal, a, 0.0), axis=1,
+                    keepdims=True)               # (Q, 1): sum_{k<=i} a_k
+    cum_r = row(cum_c)                           # (1, Q)
+    cum_last = jnp.sum(a, axis=1, keepdims=True)  # (1, 1)
+
+    # intra-chunk: scores[i,j] = (C_i . B_j) dt_j exp(cum_i - cum_j), j <= i
+    decay = jnp.where(causal, jnp.exp(cum_c - cum_r), 0.0)
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
-    scores = cb * decay * dt[None, :]
+    scores = cb * decay * dt
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (Q,P)
 
     # inter-chunk: y_i += exp(cum_i) * C_i . h_prev
     h = h_ref[...]                               # (P, N)
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(
         Cm, h, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)      # (Q,N)x(P,N)^T -> (Q,P)
 
     # state update: h' = exp(cum_Q) h + sum_j exp(cum_Q - cum_j) dt_j x_j B_j^T
-    w = (jnp.exp(cum[-1] - cum) * dt)[:, None] * x              # (Q,P)
-    h_new = jnp.exp(cum[-1]) * h + jax.lax.dot_general(
+    w = jnp.exp(cum_last - cum_c) * col(dt) * x                   # (Q,P)
+    h_new = jnp.exp(cum_last) * h + jax.lax.dot_general(
         w, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # (P,N)
     h_ref[...] = h_new
@@ -74,7 +91,7 @@ def ssd_scan(x, Bm, Cm, dt, a, h0=None, *, chunk: int = DEFAULT_CHUNK,
     B, H, S, P = x.shape
     N = Bm.shape[-1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     Q = min(chunk, S)
     pad = (-S) % Q
     if pad:  # a=0, dt=0 padding leaves the state untouched
@@ -83,6 +100,9 @@ def ssd_scan(x, Bm, Cm, dt, a, h0=None, *, chunk: int = DEFAULT_CHUNK,
                            + [(0, 0)] * (t.ndim - 3))
         x, Bm, Cm, dt, a = map(padf, (x, Bm, Cm, dt, a))
     nc = x.shape[2] // Q
+    # dt and a go in as (B, H, 1, S): a (1, Q) block is a lane-dense row,
+    # which Mosaic tiles; a (1, 1, Q) block of (B, H, S) it refuses
+    dt, a = dt[:, :, None, :], a[:, :, None, :]
     if h0 is None:
         h0 = jnp.zeros((B, H, P, N), jnp.float32)
 
@@ -94,8 +114,8 @@ def ssd_scan(x, Bm, Cm, dt, a, h0=None, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1, 1, Q), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[

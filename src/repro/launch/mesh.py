@@ -8,14 +8,22 @@ jax device state.  Single pod: 16x16 = 256 chips (data, model).  Multi-pod:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.costmodel import MeshShape
+
+
+def _auto(n: int) -> tuple:
+    """Auto axes: the model code places activations with
+    ``with_sharding_constraint``, which only names Auto axes (jax's default
+    for ``make_mesh`` is Explicit)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
 def mesh_shape_of(mesh) -> MeshShape:
@@ -29,4 +37,4 @@ def make_host_mesh(n_devices: int | None = None, model: int = 1):
     exist, factored as (data, model))."""
     n = n_devices or len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"), _auto(2))

@@ -61,8 +61,9 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_arch, reduce_for_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.models import transformer as T
+from repro.serving.engine import init_params
 
 
 def main():
@@ -129,11 +130,13 @@ def main():
         ap.error("--metrics-every needs --metrics-out (snapshots go to "
                  "<metrics-out>.jsonl)")
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         arch = reduce_for_smoke(arch)
-    params = T.init_lm(jax.random.PRNGKey(0), arch)
     mesh = make_host_mesh()
+    params = init_params(arch, mesh, jax.random.PRNGKey(0), slots=args.slots,
+                         max_len=args.max_len)
     rng = np.random.default_rng(0)
     if args.share_prefix:
         plen = (args.prompt_len if args.shared_prefix_len is None

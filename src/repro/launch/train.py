@@ -16,6 +16,7 @@ from repro.configs import ARCHS, get_arch, reduce_for_smoke
 from repro.configs.base import ShapeSpec
 from repro.core.asa import AdaptiveScheduler
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.runtime.trainer import TrainConfig, Trainer
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--replan-every", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         arch = reduce_for_smoke(arch)

@@ -9,6 +9,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
@@ -19,7 +20,8 @@ from repro.models import moe as MOE
 Params = dict
 Array = jax.Array
 
-ZERO = jnp.zeros((), jnp.float32)
+# a numpy scalar: a jnp one would open the device when this module loads
+ZERO = np.zeros((), np.float32)
 
 
 def norm_init(arch: ArchConfig, d: int, dtype) -> Params:

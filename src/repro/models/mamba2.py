@@ -168,7 +168,10 @@ def _ssd_chunked(cfg: Mamba2Config, x, Bm, Cm, dt_a, h0=None):
         x_c, B_c, C_c, dt_c, a_c = inp                 # (B,Q,...)
         cum = jnp.cumsum(a_c, axis=1)                  # (B,Q,H)
         seg = cum[:, :, None, :] - cum[:, None, :, :]  # (B,Q,Q,H)
-        decay = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)
+        # mask before exp: above the diagonal seg is a positive sum of
+        # -a that overflows exp at real decay rates, and the backward of a
+        # mask applied after exp is then 0 * inf = nan
+        decay = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
         cb = jnp.einsum("bign,bjgn->bijg", C_c, B_c)   # (B,Q,Q,G)
         cb = jnp.repeat(cb, hpg, axis=-1)              # g -> h
         scores = cb * decay * dt_c[:, None, :, :]

@@ -12,7 +12,6 @@ import time
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
@@ -33,7 +32,7 @@ class TrainConfig:
     total_steps: int = 1000
     clip_norm: float = 1.0
     microbatches: int = 0            # 0 = take from the ASA plan
-    remat: str = "none"
+    remat: Optional[str] = None      # None = the policy the plan sized for
     impl: str = "xla"
     checkpoint_every: int = 200
     replan_every: int = 0            # 0 = only on monitor trigger
@@ -80,7 +79,7 @@ class Trainer:
         mb = self.cfg.microbatches or self.plan.microbatches
         step_fn = ST.make_train_step(
             self.arch, self.opt, microbatches=mb, impl=self.cfg.impl,
-            remat=self.cfg.remat, act_sharding=act_ns,
+            remat=self.cfg.remat or self.sched.remat, act_sharding=act_ns,
             clip_norm=self.cfg.clip_norm)
         def opt_specs_fn(osds):
             return SH.opt_state_specs(osds, pspecs, ms)
@@ -88,12 +87,15 @@ class Trainer:
         self._step_fn, self._pns, self._opt_specs_fn = step_fn, pns, opt_specs_fn
         return changed
 
-    def _jit(self, params, opt_state):
+    def _opt_shardings(self, opt_state):
         opt_sds = jax.eval_shape(lambda o: o, opt_state)
-        ons = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
-                           self._opt_specs_fn(opt_sds))
-        self._jitted = ST.jit_step("train", self._step_fn,
-                                   out_shardings=(self._pns, ons, None))
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s),
+                            self._opt_specs_fn(opt_sds))
+
+    def _jit(self, params, opt_state):
+        self._jitted = ST.jit_step(
+            "train", self._step_fn,
+            out_shardings=(self._pns, self._opt_shardings(opt_state), None))
 
     # ------------------------------------------------------------------
     def init_state(self, rng: Optional[jax.Array] = None):
@@ -102,7 +104,10 @@ class Trainer:
         params = jax.jit(
             lambda k: T.init_lm(k, self.arch), out_shardings=pns)(rng)
         opt_init, _ = self.opt
-        opt_state = jax.jit(opt_init)(params)
+        # born with the shardings the step returns, so step 2 reuses the
+        # program step 1 compiled
+        opt_state = jax.jit(opt_init, out_shardings=self._opt_shardings(
+            jax.eval_shape(opt_init, params)))(params)
         return params, opt_state
 
     def maybe_restore(self, params, opt_state):
@@ -124,10 +129,12 @@ class Trainer:
               log_every: int = 10, on_metrics: Optional[Callable] = None):
         if self._jitted is None:
             self._jit(params, opt_state)
+        # each device gets only its rows of the batch (leading axis)
+        batch_ns = NamedSharding(self.mesh, P(SH.batch_axes(
+            mesh_shape_of(self.mesh), self.shape.global_batch)))
         metrics_hist = []
         for _ in range(steps):
-            batch = next(data_iter)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            batch = jax.device_put(dict(next(data_iter)), batch_ns)
             t0 = time.perf_counter()
             params, opt_state, metrics = self._jitted(params, opt_state, batch)
             jax.block_until_ready(metrics["loss"])
@@ -160,9 +167,7 @@ class Trainer:
         _, pns, _ = self._specs()
         params = jax.device_put(params, pns)
         # optimizer state: reshard step scalar + moments like params
-        opt_sds = jax.eval_shape(lambda o: o, opt_state)
-        ons = jax.tree.map(lambda s: NamedSharding(new_mesh, s),
-                           self._opt_specs_fn(opt_sds))
-        opt_state = jax.device_put(opt_state, ons)
+        opt_state = jax.device_put(opt_state,
+                                   self._opt_shardings(opt_state))
         self._jit(params, opt_state)
         return params, opt_state

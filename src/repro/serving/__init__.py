@@ -103,7 +103,7 @@ from repro.serving.cache_manager import (PAGEABLE_KINDS, SLOT_STATE_KINDS,
 from repro.serving.detok import (Detokenizer, StopStringMatcher,
                                  default_detokenizer)
 from repro.serving.engine import (ContinuousBatchingEngine, Request,
-                                  RequestOutput)
+                                  RequestOutput, init_params)
 from repro.serving.prefix_hash import chain_keys
 from repro.serving.export import (SnapshotWriter, atomic_write_text,
                                   prometheus_text)
@@ -116,6 +116,7 @@ from repro.serving.telemetry import (Counter, Gauge, LogHistogram,
 from repro.serving.tracing import ChromeTracer, validate_chrome_trace
 
 __all__ = ["ContinuousBatchingEngine", "Request", "RequestOutput",
+           "init_params",
            "SamplingParams", "GREEDY", "ServingMetrics", "BlockAllocator",
            "PagedKVCache", "UnifiedCacheManager", "RequestScheduler",
            "PAGEABLE_KINDS", "SLOT_STATE_KINDS",

@@ -1,10 +1,12 @@
 """Subprocess launcher for engine replica workers.
 
 Spawns N copies of ``python -m repro.serving.cluster.worker``, each with
-its own environment: ``XLA_FLAGS --xla_force_host_platform_device_count``
-is set *per child* (replacing any inherited forced count) so every
-replica owns its own mesh slice — the parent router process never
-imports jax and is unaffected.  Workers dial back to the router's
+its own environment: on the CPU, ``XLA_FLAGS
+--xla_force_host_platform_device_count`` is set *per child* (replacing
+any inherited forced count) so every replica owns its own mesh slice; on
+a TPU host, each replica is pinned to its own chip, and a cluster of more
+replicas than chips is refused.  The parent router process never imports
+jax and is unaffected.  Workers dial back to the router's
 listening socket; ``accept_workers`` pairs each accepted connection with
 its ``ready`` message so the router gets handles in replica order no
 matter the connect order.
@@ -21,6 +23,7 @@ are the ones that pay device-runtime startup.
 """
 from __future__ import annotations
 
+import glob
 import os
 import socket
 import subprocess
@@ -51,12 +54,59 @@ def worker_command(*, connect: str, replica_id: int, arch: str,
     return cmd
 
 
-def worker_env(devices_per_worker: int = 1) -> dict:
-    """Child environment with the per-worker mesh slice applied.  Any
-    inherited forced host-device count is *replaced*, not appended —
-    XLA honors the last occurrence, but a stale flag would make the
-    intent unreadable in ps output."""
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+# PCI device ids of TPU chips (v4, v5p, v5e, v6e)
+_TPU_PCI_DEVICES = {"0x005e", "0x0062", "0x0063", "0x006f"}
+TPU_PROCESS_PORT_BASE = 8476     # replica i's slice builder listens on +i
+
+
+def tpu_chip_count(pci_root: str = "/sys/bus/pci/devices") -> int:
+    """TPU chips attached to this host, read from PCI ids: no jax, no
+    libtpu, so the router process never holds a chip."""
+    n = 0
+    for vendor in glob.glob(os.path.join(pci_root, "*", "vendor")):
+        try:
+            with open(vendor) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+                n += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    return n
+
+
+def tpu_chips_for_workers(env=None) -> int:
+    """Chips the workers would open: 0 off a TPU host, or when
+    ``JAX_PLATFORMS`` keeps jax off the TPU."""
+    platforms = (os.environ if env is None else env).get("JAX_PLATFORMS")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return tpu_chip_count()
+
+
+def worker_env(replica_id: int = 0, devices_per_worker: int = 1) -> dict:
+    """Child environment with the per-worker device slice applied.
+
+    On a TPU host, replica ``i`` sees chip ``i`` only, as a one-chip slice
+    with its own slice-builder port, so replicas never open each other's
+    chips.  Elsewhere, any inherited forced host-device count is
+    *replaced*, not appended — XLA honors the last occurrence, but a stale
+    flag would make the intent unreadable in ps output."""
     env = dict(os.environ)
+    chips = tpu_chips_for_workers(env)
+    if chips:
+        if devices_per_worker != 1:
+            raise ClusterError(f"on a TPU host each replica holds one "
+                               f"chip; devices_per_worker="
+                               f"{devices_per_worker}")
+        if not 0 <= replica_id < chips:
+            raise ClusterError(f"replica {replica_id} has no chip: this "
+                               f"host has {chips}")
+        env.update(TPU_VISIBLE_CHIPS=str(replica_id),
+                   TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_PORT=str(TPU_PROCESS_PORT_BASE + replica_id))
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
     if devices_per_worker > 1:
@@ -78,13 +128,17 @@ class WorkerProcesses:
     def spawn(cls, n_replicas: int, *, connect: str, arch: str,
               devices_per_worker: int = 1,
               **worker_kwargs) -> "WorkerProcesses":
-        env = worker_env(devices_per_worker)
+        chips = tpu_chips_for_workers()
+        if chips and n_replicas > chips:
+            raise ClusterError(f"{n_replicas} replicas need one chip each; "
+                               f"this host has {chips}")
         procs = []
         try:
             for i in range(n_replicas):
                 cmd = worker_command(connect=connect, replica_id=i,
                                      arch=arch, **worker_kwargs)
-                procs.append(subprocess.Popen(cmd, env=env))
+                procs.append(subprocess.Popen(
+                    cmd, env=worker_env(i, devices_per_worker)))
         except Exception:
             cls(procs).stop(grace=2.0)
             raise

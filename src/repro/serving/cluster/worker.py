@@ -15,10 +15,10 @@ Replicas are pure data-parallel and never communicate with each other;
 for real multi-host meshes, and single-machine CI never takes that
 branch, so no collectives are needed.
 
-Parity contract: params come from ``T.init_lm(PRNGKey(0), arch)`` — the
-same deterministic init on every replica — and sampling keys are
-``fold_in(seed, absolute_position)``, so a request produces bit-identical
-tokens on ANY replica.  The CI cluster job asserts cluster outputs ==
+Parity contract: params come from ``engine.init_params`` with
+``PRNGKey(0)`` — the same deterministic init on every replica — and
+sampling keys are ``fold_in(seed, absolute_position)``, so a request
+produces bit-identical tokens on ANY replica.  The CI cluster job asserts cluster outputs ==
 single-process outputs token for token.
 
 The pump loop is single-threaded and clock-free: it alternates between
@@ -195,9 +195,10 @@ def build_engine(args):
     import jax
 
     from repro.configs import get_arch, reduce_for_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
-    from repro.models import transformer as T
-    from repro.serving import ContinuousBatchingEngine, ServingMetrics
+    from repro.serving import (ContinuousBatchingEngine, ServingMetrics,
+                               init_params)
 
     if args.distributed:
         # real multi-host wiring — never taken on single-machine CI, so
@@ -205,11 +206,13 @@ def build_engine(args):
         jax.distributed.initialize(
             coordinator_address=args.coordinator,
             num_processes=args.num_processes, process_id=args.process_id)
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         arch = reduce_for_smoke(arch)
-    params = T.init_lm(jax.random.PRNGKey(0), arch)   # identical per replica
     mesh = make_host_mesh()
+    params = init_params(arch, mesh, jax.random.PRNGKey(0),   # same per replica
+                         slots=args.slots, max_len=args.max_len)
     return ContinuousBatchingEngine(
         arch, params, mesh, slots=args.slots, max_len=args.max_len,
         block_size=args.block_size, num_blocks=args.num_blocks,

@@ -105,6 +105,7 @@ from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.asa import AdaptiveScheduler
 from repro.core.profiler import StepMonitor
 from repro.launch.mesh import mesh_shape_of
+from repro.models import transformer as T
 from repro.runtime import steps as ST
 from repro.serving.cache_manager import UnifiedCacheManager, check_servable
 from repro.serving.metrics import ServingMetrics
@@ -219,6 +220,29 @@ class _Slot:
         return self.req is not None
 
 
+def serving_plan(arch: ArchConfig, mesh, *, slots: int, max_len: int,
+                 asa: Optional[AdaptiveScheduler] = None):
+    """The ASA plan an engine with this geometry shards its weights and
+    cache by."""
+    sched = asa or AdaptiveScheduler(faithful=False)
+    return sched.plan(arch, ShapeSpec("serve", max_len, slots, "decode"),
+                      mesh_shape_of(mesh))
+
+
+def param_shardings(plan, mesh):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), plan.param_specs())
+
+
+def init_params(arch: ArchConfig, mesh, key, *, slots: int, max_len: int,
+                asa: Optional[AdaptiveScheduler] = None):
+    """``T.init_lm(key, arch)`` created directly with the shardings an
+    engine of this geometry serves from, so no device ever holds the whole
+    model unplaced."""
+    plan = serving_plan(arch, mesh, slots=slots, max_len=max_len, asa=asa)
+    return jax.jit(lambda k: T.init_lm(k, arch),
+                   out_shardings=param_shardings(plan, mesh))(key)
+
+
 class ContinuousBatchingEngine:
     def __init__(self, arch: ArchConfig, params, mesh, *,
                  slots: int = 4, max_len: int = 512,
@@ -244,17 +268,15 @@ class ContinuousBatchingEngine:
         max_blocks_per_seq = blocks_for(max_len, block_size)
         if num_blocks is None:
             num_blocks = slots * max_blocks_per_seq + 1   # +1: null block
-        shape = ShapeSpec("serve", max_len, slots, "decode")
-        sched = asa or AdaptiveScheduler(faithful=False)
-        self.plan = sched.plan(arch, shape, mesh_shape_of(mesh))
+        self.plan = serving_plan(arch, mesh, slots=slots, max_len=max_len,
+                                 asa=asa)
         cdtype = jnp.float32 if arch.dtype == "float32" else jnp.bfloat16
         self.cache = UnifiedCacheManager(
             arch, PagedCacheConfig(block_size, num_blocks, max_blocks_per_seq,
                                    slots=slots, share_prefix=share_prefix),
             dtype=cdtype, mesh=mesh, specs=self.plan.paged_cache_specs())
-        self.params = jax.device_put(
-            params, jax.tree.map(lambda s: NamedSharding(mesh, s),
-                                 self.plan.param_specs()))
+        self.params = jax.device_put(params,
+                                     param_shardings(self.plan, mesh))
         sampler = make_sampler(arch.vocab)
         # donation follows ST.STEP_DONATION (the cache carry is donated,
         # params never are) — audited by analysis/tracecheck.py

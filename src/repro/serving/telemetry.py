@@ -15,7 +15,8 @@ path, so none of them may grow with run length:
     the right trade for latency-shaped (long-tailed, positive)
     distributions, and the reason memory stays fixed (~a few hundred int
     buckets) no matter how many samples stream in.  ``count``/``total``/
-    ``vmin``/``vmax`` are exact; ``total`` accumulates in record order, so
+    ``vmin``/``vmax`` are exact; ``total`` is a Neumaier-compensated sum in
+    record order, the algorithm Python's own ``sum`` uses for floats, so
     ``mean`` is bit-identical to ``sum(samples)/len(samples)``.
 
 ``Counter`` / ``Gauge``
@@ -116,7 +117,8 @@ class LogHistogram:
         self._n = math.ceil(math.log(hi / lo) / self._log_growth)
         self.counts = [0] * (self._n + 2)      # [under, 1..n, over]
         self.count = 0
-        self.total = 0.0
+        self._sum = 0.0
+        self._comp = 0.0                        # Neumaier compensation
         self.vmin: Optional[float] = None
         self.vmax: Optional[float] = None
 
@@ -137,12 +139,23 @@ class LogHistogram:
 
     def record(self, x: float) -> None:
         self.count += 1
-        self.total += x
+        t = self._sum + x
+        if abs(self._sum) >= abs(x):
+            self._comp += (self._sum - t) + x
+        else:
+            self._comp += (x - t) + self._sum
+        self._sum = t
         if self.vmin is None or x < self.vmin:
             self.vmin = x
         if self.vmax is None or x > self.vmax:
             self.vmax = x
         self.counts[self._index(x)] += 1
+
+    @property
+    def total(self) -> float:
+        if self._comp and math.isfinite(self._comp):
+            return self._sum + self._comp
+        return self._sum
 
     @property
     def mean(self) -> Optional[float]:

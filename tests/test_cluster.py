@@ -889,8 +889,7 @@ def test_e2e_generate_parity_with_single_process(live_cluster):
 
     from repro.configs import get_arch, reduce_for_smoke
     from repro.launch.mesh import make_host_mesh
-    from repro.models import transformer as T
-    from repro.serving import ContinuousBatchingEngine, Request
+    from repro.serving import ContinuousBatchingEngine, Request, init_params
 
     proc, url, pids = live_cluster
     rng = np.random.default_rng(7)
@@ -904,8 +903,10 @@ def test_e2e_generate_parity_with_single_process(live_cluster):
         assert status == 200
         cluster_out.append(json.loads(body))
 
-    params = T.init_lm(jax.random.PRNGKey(0), arch)
-    eng = ContinuousBatchingEngine(arch, params, make_host_mesh(),
+    mesh = make_host_mesh()
+    params = init_params(arch, mesh, jax.random.PRNGKey(0), slots=4,
+                         max_len=64)             # as each worker makes them
+    eng = ContinuousBatchingEngine(arch, params, mesh,
                                    slots=4, max_len=64, block_size=8,
                                    prefill_chunk=16)
     ref = eng.generate([Request(id=i, prompt=p, max_new_tokens=8)
@@ -982,8 +983,6 @@ def test_cluster_bench_criteria():
     import argparse
     import importlib.util
 
-    from repro.launch.mesh import make_host_mesh
-
     spec = importlib.util.spec_from_file_location(
         "serve_bench",
         os.path.join(REPO, "benchmarks", "serve_bench.py"))
@@ -993,10 +992,78 @@ def test_cluster_bench_criteria():
                               block_size=16, prefill_chunk=32,
                               prefix_len=64, cluster_replicas=2,
                               sanitize=False)
-    row = sb.bench_cluster("qwen3-8b", args, make_host_mesh())
+    row = sb.bench_cluster("qwen3-8b", args)
     assert abs(row["hit_rate_delta_vs_single_process"]) <= 0.05, row
     assert row["affinity"]["total_tokens"] > 0
     assert row["saturated_2_replica"]["total_tokens"] > 0
     if os.environ.get("REPRO_ASSERT_CLUSTER_SCALING") == "1" \
             and (os.cpu_count() or 1) >= 4:
         assert row["scaling_tokens_per_sec"] >= 1.7, row
+
+
+# ---------------------------------------------------------------------------
+# one chip per worker (launcher.worker_env on a TPU host)
+# ---------------------------------------------------------------------------
+
+def _fake_pci(root, devices):
+    for i, (vendor, device) in enumerate(devices):
+        d = root / f"0000:00:{i:02x}.0"
+        d.mkdir()
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+    return str(root)
+
+
+def test_tpu_chip_count_reads_pci_ids(tmp_path):
+    from repro.serving.cluster.launcher import tpu_chip_count
+    root = _fake_pci(tmp_path, [("0x1ae0", "0x0063")] * 4       # v5e chips
+                     + [("0x1ae0", "0x0001"),                   # not a TPU
+                        ("0x8086", "0x0063")])                  # other vendor
+    assert tpu_chip_count(root) == 4
+    assert tpu_chip_count(str(tmp_path / "absent")) == 0
+
+
+@pytest.fixture
+def tpu_host(monkeypatch):
+    """A host with four TPU chips, jax free to use them."""
+    from repro.serving.cluster import launcher
+    monkeypatch.setattr(launcher, "tpu_chip_count", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    return launcher
+
+
+def test_worker_env_pins_each_replica_to_its_own_chip(tpu_host):
+    envs = [tpu_host.worker_env(i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert "xla_force_host_platform_device_count" \
+            not in e.get("XLA_FLAGS", "")
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+
+
+def test_worker_env_refuses_a_replica_without_a_chip(tpu_host):
+    with pytest.raises(ClusterError, match="no chip"):
+        tpu_host.worker_env(4)
+    with pytest.raises(ClusterError, match="one chip"):
+        tpu_host.worker_env(0, devices_per_worker=2)
+
+
+def test_spawn_refuses_more_replicas_than_chips(tpu_host, monkeypatch):
+    def no_popen(*a, **k):
+        raise AssertionError("a worker was started")
+    monkeypatch.setattr(tpu_host.subprocess, "Popen", no_popen)
+    with pytest.raises(ClusterError, match="5 replicas"):
+        tpu_host.WorkerProcesses.spawn(5, connect="127.0.0.1:1",
+                                       arch="qwen3-8b")
+
+
+def test_worker_env_unpinned_off_the_tpu(tpu_host, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")     # chips present, not used
+    e = tpu_host.worker_env(7, devices_per_worker=2)
+    assert "TPU_VISIBLE_CHIPS" not in e
+    assert e["XLA_FLAGS"].endswith("--xla_force_host_platform_device_count=2")
+    monkeypatch.setattr(tpu_host, "tpu_chip_count", lambda: 0)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "TPU_VISIBLE_CHIPS" not in tpu_host.worker_env(0)

@@ -16,7 +16,7 @@ import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig, Segment
 from repro.models import transformer as T
 from repro.optim import optimizers as O
@@ -29,7 +29,7 @@ arch = ArchConfig(name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
                   param_dtype="float32")
 
 def run(mesh_shape, axes, tok_spec, w_col, w_row):
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = jax.make_mesh(mesh_shape, axes, (AxisType.Auto,) * len(axes))
     opt = O.adamw(1e-3)
     step = ST.make_train_step(arch, opt)
     params = T.init_lm(jax.random.PRNGKey(0), arch)
@@ -69,7 +69,7 @@ print(json.dumps({"single": single, "dp": dp, "tp": tp}))
 def test_sharded_training_is_same_math():
     src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # the 8 virtual devices are CPU ones
     out = subprocess.run([sys.executable, "-c", SCRIPT, src],
                          capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]

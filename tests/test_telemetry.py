@@ -27,7 +27,11 @@ _PARAMS_CACHE: dict[str, dict] = {}
 
 def _params_for(arch):
     if arch.name not in _PARAMS_CACHE:
-        _PARAMS_CACHE[arch.name] = T.init_lm(jax.random.PRNGKey(0), arch)
+        # the goldens' weights: non-partitionable threefry (see
+        # tests/test_serving.py::_params_for)
+        with jax.threefry_partitionable(False):
+            _PARAMS_CACHE[arch.name] = T.init_lm(jax.random.PRNGKey(0),
+                                                 arch)
     return _PARAMS_CACHE[arch.name]
 
 
