@@ -38,8 +38,9 @@
 Observability (continuous engine only):
 --trace-out PATH     record a Chrome trace-event JSON of the whole run —
                      one track per engine phase (admission / prefix-match /
-                     prefill / decode / sample host-sync) plus a lifecycle
-                     span per request with preemption/resume annotations.
+                     prefill launch and sync / decode launch / sample
+                     host-sync / commit) plus a lifecycle span per request
+                     with preemption/resume annotations.
                      Open it in Perfetto (ui.perfetto.dev) or
                      chrome://tracing.
 --prom-out PATH      write the final metrics registry as Prometheus text

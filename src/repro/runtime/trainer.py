@@ -4,6 +4,15 @@ Integrates: ASA planning + periodic re-planning (re-profile -> re-solve ->
 reshard -> re-jit), grad-accum microbatching, checkpoint/restart (exact
 resume: step, rng, data offset), elastic mesh resize, straggler-aware input
 dispatch (data.HostShardedLoader), and live step-time monitoring.
+
+Each step's host phases are spans (core/profiler.py ``Span``) on the
+profiler's clock: ``train.put`` (the next batch and its transfer),
+``train.dispatch`` (the call of the jitted step until it returns),
+``train.sync`` (waiting for the loss, reading the metrics back),
+``train.replan`` (the drift monitor, and the planner, reshard and re-jit
+when it fires) and ``train.checkpoint``.  A step's metrics carry
+``step_time_s`` (dispatch to loss ready) and ``compiles`` (traces and
+compiles made during its dispatch).
 """
 from __future__ import annotations
 
@@ -18,6 +27,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core import sharding as SH
 from repro.core.asa import AdaptiveScheduler, SchedulePlan
+from repro.core.profiler import Span, compile_count
 from repro.launch.mesh import mesh_shape_of
 from repro.models import transformer as T
 from repro.optim import optimizers as O
@@ -134,26 +144,37 @@ class Trainer:
             mesh_shape_of(self.mesh), self.shape.global_batch)))
         metrics_hist = []
         for _ in range(steps):
-            batch = jax.device_put(dict(next(data_iter)), batch_ns)
+            with Span("train.put", step=self.step + 1):
+                batch = jax.device_put(dict(next(data_iter)), batch_ns)
+            compiles0 = compile_count()
             t0 = time.perf_counter()
-            params, opt_state, metrics = self._jitted(params, opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
+            with Span("train.dispatch") as sp:
+                params, opt_state, metrics = self._jitted(params, opt_state,
+                                                          batch)
+                compiles = compile_count() - compiles0
+                sp.set(compiles=compiles)
+            with Span("train.sync"):
+                jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                m = {k: float(v) for k, v in metrics.items()}
             self.step += 1
             self.data_offset += 1
 
-            if self.sched.record_step(dt) or (
-                    self.cfg.replan_every
-                    and self.step % self.cfg.replan_every == 0):
-                if self._replan():     # strategy switch: reshard + re-jit
-                    params = jax.device_put(params, self._pns)
-                    self._jit(params, opt_state)
+            with Span("train.replan"):
+                if self.sched.record_step(dt) or (
+                        self.cfg.replan_every
+                        and self.step % self.cfg.replan_every == 0):
+                    if self._replan():     # strategy switch: reshard + re-jit
+                        params = jax.device_put(params, self._pns)
+                        self._jit(params, opt_state)
 
             if self.ckpt and self.step % self.cfg.checkpoint_every == 0:
-                self.ckpt.save(self.step, {"params": params, "opt": opt_state},
-                               extra={"data_offset": self.data_offset})
-            m = {k: float(v) for k, v in metrics.items()}
+                with Span("train.checkpoint"):
+                    self.ckpt.save(self.step,
+                                   {"params": params, "opt": opt_state},
+                                   extra={"data_offset": self.data_offset})
             m["step_time_s"] = dt
+            m["compiles"] = compiles
             metrics_hist.append(m)
             if on_metrics and self.step % log_every == 0:
                 on_metrics(self.step, m)

@@ -69,16 +69,23 @@ one injectable ``clock`` — tests pass a synthetic clock and get coherent
 TTFT/TPOT instead of mixing fake submit times with real perf_counter
 stamps.
 
-Observability: every step is phase-timed (admission / prefix-match /
-prefill chunk / decode / sample host-sync) into the ServingMetrics
-log-bucketed histograms, a ``StepMonitor`` (core/profiler.py) tracks
-step-time EMA drift as the adaptive scheduler's re-profile trigger, and
-an optional ``tracer`` (serving/tracing.py ChromeTracer) records the same
-clock values as Perfetto-loadable spans — per-phase tracks plus a
-lifecycle span per request with admitted/first-token/preempt/resume
-annotations.  With ``tracer=None`` (default) no trace work happens at
-all; an optional ``snapshot`` (serving/export.py SnapshotWriter) appends
-a windowed-signal JSONL line every N seconds of engine time.
+Observability: a step is tiled by host spans from the shared primitive
+(core/profiler.py ``Span``), one per phase: ``engine.admit``,
+``engine.prefill.launch``, ``engine.prefill.sync`` (the first token's
+readback), ``engine.decode.launch``, ``engine.decode.sync`` (the token
+readback) and ``engine.commit`` (tokens, finishes, prefix commits and, at
+the step's tail, per-step metrics and the step's ``compiles``).  Each is a
+``jax.profiler.TraceAnnotation``, so under a profiler trace it sits on the
+device trace's clock and the device's idle time can be charged to the
+phase the host was in.  The same span, timed by the engine's clock, feeds
+the ServingMetrics phase histograms (serving/metrics.py ``SPAN_PHASES``
+names them) and, when set, the optional ``tracer`` (serving/tracing.py
+ChromeTracer: per-phase tracks plus a lifecycle span per request with
+admitted/first-token/preempt/resume annotations).  The ``compiles``
+counter counts traces and compiles made inside steps.  With
+``tracer=None`` (default) no Chrome-trace work happens; an optional
+``snapshot`` (serving/export.py SnapshotWriter) appends a windowed-signal
+JSONL line every N seconds of engine time.
 
 Greedy decode is token-for-token identical to the retired wave Server: the
 paged attention paths mask exactly the same prefix (layers._paged_sdpa,
@@ -103,12 +110,12 @@ from jax.sharding import NamedSharding
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.asa import AdaptiveScheduler
-from repro.core.profiler import StepMonitor
+from repro.core.profiler import Span, compile_count
 from repro.launch.mesh import mesh_shape_of
 from repro.models import transformer as T
 from repro.runtime import steps as ST
 from repro.serving.cache_manager import UnifiedCacheManager, check_servable
-from repro.serving.metrics import ServingMetrics
+from repro.serving.metrics import SPAN_PHASES, ServingMetrics
 from repro.serving.paged_cache import PagedCacheConfig, blocks_for
 from repro.serving.sampling import GREEDY, SamplingParams, make_sampler
 from repro.serving.scheduler import RequestScheduler
@@ -254,9 +261,7 @@ class ContinuousBatchingEngine:
                  metrics: Optional[ServingMetrics] = None,
                  clock: Callable[[], float] = time.perf_counter,  # reprolint: disable=clock-injection
                  on_token: Optional[Callable[[int, int], None]] = None,
-                 tracer=None, snapshot=None,
-                 step_monitor: Optional[StepMonitor] = None,
-                 sanitizer=None):
+                 tracer=None, snapshot=None, sanitizer=None):
         check_servable(arch)           # precise error for excluded archs
         self.arch, self.mesh = arch, mesh
         self.max_len, self.prefill_chunk = max_len, prefill_chunk
@@ -298,10 +303,9 @@ class ContinuousBatchingEngine:
         self.metrics = metrics or ServingMetrics()
         # observability: ChromeTracer (serving/tracing.py) and
         # SnapshotWriter (serving/export.py) are optional and cost nothing
-        # when absent; the StepMonitor always runs (a handful of floats)
+        # when absent
         self.tracer = tracer
         self.snapshot = snapshot
-        self.step_monitor = step_monitor or StepMonitor()
         # live references: summary()/exporters read the scheduler counters
         # and cache geometry at call time instead of per-step pushes
         self.metrics.scheduler_stats = self.scheduler.stats
@@ -555,92 +559,102 @@ class ContinuousBatchingEngine:
         if not prefilling:
             return False
         slot = min(prefilling, key=lambda s: s.req._sched_seq)
-        st = slot.req
-        ctx = st.context()
-        chunk = ctx[slot.prefill_pos: slot.prefill_pos + self.prefill_chunk]
-        n_new = len(chunk)
-        if n_new < self.prefill_chunk:      # pad: the step traces one shape
-            chunk = np.concatenate(
-                [chunk, np.zeros(self.prefill_chunk - n_new, np.int32)])
-        table = self.cache.table_array([st.id])
-        tok, logp, self.cache.pools = self._prefill(
-            self.params, self.cache.pools, jnp.asarray(chunk[None, :]),
-            jnp.asarray([slot.prefill_pos], jnp.int32), jnp.asarray(table),
-            jnp.asarray([n_new], jnp.int32),
-            jnp.asarray([slot.idx], jnp.int32),
-            *self._sampling_rows([st]))
-        slot.prefill_pos += n_new
-        slot.pos = slot.prefill_pos
-        self.cache.commit_prefix(st.id, ctx, slot.prefill_pos)
-        self.metrics.prefill_chunks += 1
-        if slot.prefill_pos == len(ctx):
-            # the fused sampler produced this chunk's next token at absolute
-            # position len(ctx) — only the final chunk's draw is real
-            t = self._clock()
-            self.metrics.on_first_token(st.id, t)
-            if self.tracer is not None:
-                self.tracer.request_instant(st.id, "first_token", t)
-            reason = self._record_token(slot, int(tok[0]), float(logp[0]))
-            if reason is not None:
-                self._finish(slot, reason)
-            else:
-                slot.state = "decode"
+        st, start = slot.req, slot.prefill_pos
+        with self._span("engine.prefill.launch", rid=st.id,
+                        start=start) as sp:
+            ctx = st.context()
+            chunk = ctx[start: start + self.prefill_chunk]
+            n_new = len(chunk)
+            sp.set(tokens=n_new)
+            if n_new < self.prefill_chunk:  # pad: the step traces one shape
+                chunk = np.concatenate(
+                    [chunk, np.zeros(self.prefill_chunk - n_new, np.int32)])
+            table = self.cache.table_array([st.id])
+            tok, logp, self.cache.pools = self._prefill(
+                self.params, self.cache.pools, jnp.asarray(chunk[None, :]),
+                jnp.asarray([start], jnp.int32), jnp.asarray(table),
+                jnp.asarray([n_new], jnp.int32),
+                jnp.asarray([slot.idx], jnp.int32),
+                *self._sampling_rows([st]))
+            slot.prefill_pos += n_new
+            slot.pos = slot.prefill_pos
+        # the fused sampler produced this chunk's next token at absolute
+        # position len(ctx) — only the final chunk's draw is real
+        last = slot.prefill_pos == len(ctx)
+        if last:
+            with self._span("engine.prefill.sync", rid=st.id):
+                tok0, logp0 = int(tok[0]), float(logp[0])
+        with self._span("engine.commit") as sp:
+            self.cache.commit_prefix(st.id, ctx, slot.prefill_pos)
+            self.metrics.prefill_chunks += 1
+            if last:
+                t = self._clock()
+                self.metrics.on_first_token(st.id, t)
+                if self.tracer is not None:
+                    self.tracer.request_instant(st.id, "first_token", t)
+                reason = self._record_token(slot, tok0, logp0)
+                if reason is not None:
+                    self._finish(slot, reason)
+                else:
+                    slot.state = "decode"
+                sp.set(finished=int(reason is not None))
         return True
 
     # -- phase 3: one decode step for every decoding slot --------------
-    def _decode_step(self) -> int:
-        decoding = [s for s in self.slots if s.state == "decode"]
-        if not decoding:
-            return 0
-        # grow block tables; preempt the longest-running request on pressure
-        for slot in list(decoding):
-            if slot.req is None:       # already preempted as an earlier victim
-                continue
-            while not self.cache.reserve(slot.req.id, slot.pos + 1):
-                victims = [s.req for s in self.slots if s.busy]
-                victim = self.scheduler.pick_preemption_victim(victims)
-                vslot = next(s for s in self.slots if s.req is victim)
-                self._preempt(vslot)
-                if vslot in decoding:
-                    decoding.remove(vslot)
-                if slot.req is None:       # we preempted ourselves
-                    break
-        decoding = [s for s in decoding if s.req is not None]
-        if not decoding:
-            return 0
-        B = len(self.slots)
-        last = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        rids: list[Optional[int]] = [None] * B
-        for i, s in enumerate(self.slots):
-            if s.state == "decode":
-                last[i, 0] = s.req.out_tokens[-1]
-                pos[i] = s.pos
-                rids[i] = s.req.id
-        table = self.cache.table_array(rids)
-        # idle/prefilling rows scatter their slot-state into the null row;
-        # active rows use s.idx (NOT list position — admission/prefill
-        # reset/advance the pool row at idx, and the two may diverge)
-        sids = self.cache.slot_ids_array(
-            [s.idx if s.state == "decode" else None for s in self.slots])
-        tok, logp, self.cache.pools = self._decode(
-            self.params, self.cache.pools, jnp.asarray(last),
-            jnp.asarray(pos), jnp.asarray(table), jnp.asarray(sids),
-            *self._sampling_rows(
-                [s.req if s.state == "decode" else None for s in self.slots]))
+    def _decode_step(self):
+        """Launch one decode step for every decoding slot and read its
+        tokens back; -> (tokens, logprobs), or None when no slot decoded.
+        The tokens are recorded by ``_commit_decode``."""
+        if not any(s.state == "decode" for s in self.slots):
+            return None
+        with self._span("engine.decode.launch") as sp:
+            decoding = [s for s in self.slots if s.state == "decode"]
+            # grow block tables; preempt the longest-running request on
+            # pressure
+            for slot in list(decoding):
+                if slot.req is None:   # already preempted as an earlier victim
+                    continue
+                while not self.cache.reserve(slot.req.id, slot.pos + 1):
+                    victims = [s.req for s in self.slots if s.busy]
+                    victim = self.scheduler.pick_preemption_victim(victims)
+                    vslot = next(s for s in self.slots if s.req is victim)
+                    self._preempt(vslot)
+                    if vslot in decoding:
+                        decoding.remove(vslot)
+                    if slot.req is None:       # we preempted ourselves
+                        break
+            decoding = [s for s in decoding if s.req is not None]
+            sp.set(rows=len(decoding))
+            if not decoding:
+                return None
+            B = len(self.slots)
+            last = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            rids: list[Optional[int]] = [None] * B
+            for i, s in enumerate(self.slots):
+                if s.state == "decode":
+                    last[i, 0] = s.req.out_tokens[-1]
+                    pos[i] = s.pos
+                    rids[i] = s.req.id
+            table = self.cache.table_array(rids)
+            # idle/prefilling rows scatter their slot-state into the null
+            # row; active rows use s.idx (NOT list position —
+            # admission/prefill reset/advance the pool row at idx, and the
+            # two may diverge)
+            sids = self.cache.slot_ids_array(
+                [s.idx if s.state == "decode" else None for s in self.slots])
+            tok, logp, self.cache.pools = self._decode(
+                self.params, self.cache.pools, jnp.asarray(last),
+                jnp.asarray(pos), jnp.asarray(table), jnp.asarray(sids),
+                *self._sampling_rows(
+                    [s.req if s.state == "decode" else None
+                     for s in self.slots]))
         # the (B,) token/logprob transfer is where the host blocks on the
-        # device — timed as its own phase so the per-step breakdown
-        # separates "waiting for the step" from host-side bookkeeping
-        ts0 = self._clock()
-        nxt = np.asarray(tok)
-        lps = np.asarray(logp)
-        ts1 = self._clock()
-        self.metrics.on_phase("sample_sync", ts1 - ts0)
-        if self.tracer is not None:
-            n_sampled = sum(1 for s in decoding
-                            if not s.req.sampling.is_greedy)
-            self.tracer.phase("sample_sync", ts0, ts1,
-                              n_rows=len(decoding), n_sampled=n_sampled)
+        # device
+        with self._span("engine.decode.sync", rows=len(decoding)):
+            return np.asarray(tok), np.asarray(logp)
+
+    def _commit_decode(self, nxt: np.ndarray, lps: np.ndarray) -> None:
         self.metrics.decode_steps += 1
         for i, s in enumerate(self.slots):
             if s.state != "decode":
@@ -658,48 +672,50 @@ class ContinuousBatchingEngine:
                 self.cache.commit_prefix(s.req.id, s.req.context(), s.pos)
             if reason is not None:
                 self._finish(s, reason)
-        return len(decoding)
 
     # ------------------------------------------------------------------
+    def _span(self, name: str, **args) -> Span:
+        return Span(name, self._clock, self._on_span, **args)
+
+    def _on_span(self, sp: Span) -> None:
+        phase = SPAN_PHASES[sp.name]
+        self.metrics.on_phase(phase, sp.t1 - sp.t0)
+        if self.tracer is not None:
+            self.tracer.phase(phase, sp.t0, sp.t1, **sp.args)
+
     def step(self) -> None:
-        tr = self.tracer
+        """admit -> one prefill chunk -> one decode step, then commit."""
         t0 = self._clock()
-        admitted = self._admit()
-        t1 = self._clock()
-        prefilled = self._prefill_chunk()
-        t2 = self._clock()
-        decoded = self._decode_step()
-        t3 = self._clock()
-        # phase durations only when the phase did work — zero-work dispatch
-        # overhead must not dilute the distributions
-        if admitted:
-            self.metrics.on_phase("admission", t1 - t0)
-            if tr is not None:
-                tr.phase("admission", t0, t1, admitted=admitted)
-        if prefilled:
-            self.metrics.on_phase("prefill", t2 - t1)
-            if tr is not None:
-                tr.phase("prefill", t1, t2)
-        if decoded:
-            self.metrics.on_phase("decode", t3 - t2)
-            if tr is not None:
-                tr.phase("decode", t2, t3, n_rows=decoded)
-        util = self.cache.utilization
-        if tr is not None:
-            tr.counter("queue_depth", t3, self.scheduler.queue_depth)
-            tr.counter("block_utilization", t3, util)
-        self.metrics.on_step(self.scheduler.queue_depth,
-                             sum(s.busy for s in self.slots), len(self.slots),
-                             block_utilization=util, now=t3)
-        dur = t3 - t0
-        triggered = self.step_monitor.update(dur)
-        self.metrics.on_step_time(dur, ema=self.step_monitor.ema,
-                                  drift=self.step_monitor.drift_fraction(),
-                                  triggered=triggered)
-        if self.snapshot is not None:
-            self.snapshot.maybe_write(self.metrics, t3)
-        if self.sanitizer is not None:
-            self.sanitizer.check_engine_step(self)
+        compiles0 = compile_count()
+        with self._span("engine.admit") as sp:
+            admitted = self._admit()
+            sp.set(admitted=admitted, queue_depth=self.scheduler.queue_depth,
+                   block_utilization=self.cache.utilization)
+        self._prefill_chunk()
+        sampled = self._decode_step()
+        with self._span("engine.commit") as sp:
+            finished = len(self.completed)
+            if sampled is not None:
+                self._commit_decode(*sampled)
+            t = self._clock()
+            util = self.cache.utilization
+            if self.tracer is not None:
+                self.tracer.counter("queue_depth", t,
+                                    self.scheduler.queue_depth)
+                self.tracer.counter("block_utilization", t, util)
+            self.metrics.on_step(self.scheduler.queue_depth,
+                                 sum(s.busy for s in self.slots),
+                                 len(self.slots), block_utilization=util,
+                                 now=t)
+            self.metrics.on_step_time(t - t0)
+            compiles = compile_count() - compiles0
+            self.metrics.on_compiles(compiles)
+            if self.snapshot is not None:
+                self.snapshot.maybe_write(self.metrics, t)
+            if self.sanitizer is not None:
+                self.sanitizer.check_engine_step(self)
+            sp.set(finished=len(self.completed) - finished,
+                   compiles=compiles)
 
     @property
     def has_work(self) -> bool:

@@ -112,8 +112,7 @@ def prometheus_text(metrics, *, namespace: str = "repro_serving",
          "submitted-but-unfinished requests")
     emit("prefix_hit_rate", "gauge", s["prefix_hit_rate"],
          "prefix-cache matched/looked-up tokens (lifetime)")
-    # registry counters / gauges (scheduler refusals, re-plan triggers,
-    # step-time EMA, ...)
+    # registry counters / gauges (compiles inside engine steps, ...)
     for name, c in sorted(tele.counters.items()):
         emit(f"{name}_total", "counter", c.value, f"telemetry counter {name}")
     for name, g in sorted(tele.gauges.items()):

@@ -16,7 +16,7 @@ changed underneath:
     signal vector the adaptive scheduler (ROADMAP item 3) needs:
     ``window_signals()`` reports arrival rate, prompt-length mix, prefix
     hit rate, cache pressure, queue depth and decode throughput over the
-    trailing ``window_s`` seconds, plus the StepMonitor drift gauge;
+    trailing ``window_s`` seconds;
   * ``summary()`` distinguishes "no data" from zero: a run with no
     finished requests reports ``None`` latencies/throughput instead of a
     0.0 that reads as infinitely fast (serve_bench skips such rows).
@@ -48,8 +48,16 @@ from typing import Optional
 from repro.serving.export import atomic_write_text
 from repro.serving.telemetry import Telemetry, quantile
 
-# engine phases with their own duration histogram + trace track
-PHASES = ("admission", "prefix_match", "prefill", "decode", "sample_sync")
+# each host span of the engine (serving/engine.py) and its phase, which
+# names the phase's duration histogram and Chrome-trace track
+SPAN_PHASES = {"engine.admit": "admission",
+               "engine.prefill.launch": "prefill_launch",
+               "engine.prefill.sync": "prefill_sync",
+               "engine.decode.launch": "decode_launch",
+               "engine.decode.sync": "sample_sync",
+               "engine.commit": "commit"}
+# every phase: the spans' and the prefix match inside admission
+PHASES = ("prefix_match",) + tuple(SPAN_PHASES.values())
 
 
 def _mean(xs):
@@ -97,9 +105,9 @@ class ServingMetrics:
         self._win_util = t.window("block_utilization")
         self._win_hit = t.window("prefix_hit_tokens")
         self._win_lookup = t.window("prefix_lookup_tokens")
-        self._g_step_ema = t.gauge("step_time_ema_s")
-        self._g_step_drift = t.gauge("step_time_drift")
-        self._c_replan = t.counter("replan_triggers")
+        # traces and compiles made inside engine steps (core/profiler.py
+        # compile_count): after warm-up a nonzero count names a recompile
+        self.compiles = t.counter("compiles")
         # newest engine-clock stamp seen: the default "now" for window
         # queries, so summary() is deterministic under synthetic clocks
         self._last_t: Optional[float] = None
@@ -180,23 +188,18 @@ class ServingMetrics:
             self._win_util.record(t, block_utilization)
 
     def on_phase(self, name: str, dur_s: float):
-        """One engine phase execution (only phases that did work — the
-        per-phase breakdown measures time spent *doing*, so zero-work
-        dispatch overhead never dilutes the distributions)."""
+        """One engine span of phase ``name`` (a phase records only when its
+        span opened: the launch and sync phases only on steps that ran
+        them)."""
         self.phase[name].record(dur_s)
 
-    def on_step_time(self, dur_s: float, ema: Optional[float] = None,
-                     drift: Optional[float] = None,
-                     triggered: bool = False):
-        """Wall time of one full engine step plus the StepMonitor's view:
-        EMA, current drift fraction vs baseline, and whether this step
-        tripped the re-profile trigger the adaptive scheduler subscribes
-        to (core/profiler.StepMonitor)."""
+    def on_step_time(self, dur_s: float):
+        """Wall time of one full engine step."""
         self.step_time.record(dur_s)
-        self._g_step_ema.set(ema)
-        self._g_step_drift.set(drift)
-        if triggered:
-            self._c_replan.inc()
+
+    def on_compiles(self, n: int):
+        """Traces and compiles made during one engine step."""
+        self.compiles.inc(n)
 
     # -- report -------------------------------------------------------------
     def request_report(self, rid: int) -> dict:
@@ -219,9 +222,9 @@ class ServingMetrics:
     def window_signals(self, now: Optional[float] = None) -> dict:
         """The adaptive scheduler's input vector, over the trailing
         ``window_s`` seconds of engine time: arrival rate, prompt-length
-        mix, prefix hit rate, cache/queue pressure, decode throughput and
-        the step-time drift gauge.  ``now`` defaults to the newest stamp
-        seen, so the vector is deterministic under synthetic clocks."""
+        mix, prefix hit rate, cache/queue pressure and decode throughput.
+        ``now`` defaults to the newest stamp seen, so the vector is
+        deterministic under synthetic clocks."""
         t = self._last_t if now is None else now
         if t is None:                  # nothing recorded yet
             t = 0.0
@@ -245,9 +248,6 @@ class ServingMetrics:
             "tokens_per_sec": self._win_finished.total(t)
             / self.telemetry.window_s,
             "finished_per_sec": self._win_finished.rate(t),
-            "step_time_ema_s": self._g_step_ema.value,
-            "step_time_drift": self._g_step_drift.value,
-            "replan_triggers": self._c_replan.value,
         }
 
     def summary(self) -> dict:
@@ -351,9 +351,7 @@ class ServingMetrics:
         self._win_util = other._win_util
         self._win_hit = other._win_hit
         self._win_lookup = other._win_lookup
-        self._g_step_ema = other._g_step_ema
-        self._g_step_drift = other._g_step_drift
-        self._c_replan = other._c_replan
+        self.compiles = other.compiles
         self.preemptions = other.preemptions
         self.engine_steps = other.engine_steps
         self.prefill_chunks = other.prefill_chunks

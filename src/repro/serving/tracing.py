@@ -1,17 +1,18 @@
 """Span-based tracer emitting Chrome trace-event JSON (Perfetto-loadable).
 
 The engine owns at most ONE tracer (``ContinuousBatchingEngine(tracer=
-ChromeTracer())``); when it owns none, tracing costs nothing — every
-emission site is behind an ``if tracer is not None`` and no clock reads,
-dict builds or list appends happen.  A serve run with ``--trace-out``
-(launch/serve.py) drops the JSON next to the metrics; open it at
-https://ui.perfetto.dev (or chrome://tracing) to see:
+ChromeTracer())``); when it owns none, no Chrome-trace events are built.
+A serve run with ``--trace-out`` (launch/serve.py) drops the JSON next to
+the metrics; open it at https://ui.perfetto.dev (or chrome://tracing) to
+see:
 
   * one named track (``tid``) per engine phase — admission, prefix-match,
-    prefill chunk, decode step, sample host-sync — carrying balanced
-    B/E duration spans stamped from the engine's own clock values (the
-    same floats the phase histograms record, so trace and metrics never
-    disagree);
+    prefill launch and sync, decode launch, sample host-sync, commit —
+    carrying balanced B/E duration spans.  The phase spans come from the
+    engine's shared span primitive (core/profiler.py ``Span``): each is
+    also a ``jax.profiler.TraceAnnotation`` on the profiler's clock, and
+    the B/E stamps here are the same engine-clock floats the phase
+    histograms record, so trace and metrics never disagree;
   * an async ``request`` track per request id: a ``b``/``e`` lifecycle
     span from submit to finish (finish_reason in the end event's args)
     with ``n`` instant annotations for admitted / first_token / preempt /
@@ -34,11 +35,11 @@ import json
 from typing import Optional
 
 from repro.serving.export import atomic_write_text
+from repro.serving.metrics import PHASES
 
 # fixed track layout: one tid per engine phase, one shared tid for the
 # async request-lifecycle spans (async events nest by id, not tid)
-PHASE_TRACKS = {"admission": 1, "prefix_match": 2, "prefill": 3,
-                "decode": 4, "sample_sync": 5}
+PHASE_TRACKS = {p: tid for tid, p in enumerate(PHASES, 1)}
 REQUEST_TRACK = 10
 COUNTER_TRACK = 0
 

@@ -10,7 +10,6 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.profiler import StepMonitor
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.serving import (ChromeTracer, ContinuousBatchingEngine, Counter,
@@ -218,8 +217,9 @@ def test_trace_schema_valid_on_forced_preemption_run(tmp_path):
     assert stats["n_request_spans"] == len(reqs)
     assert stats["n_phase_spans"] > 0
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"admission", "prefill", "decode", "sample_sync",
-            "preempt", "resume", "first_token", "admitted"} <= names
+    assert {"admission", "prefill_launch", "prefill_sync", "decode_launch",
+            "sample_sync", "commit", "preempt", "resume", "first_token",
+            "admitted"} <= names
     # every phase track got a thread_name metadata record
     meta = [e for e in trace["traceEvents"] if e["ph"] == "M"]
     assert any(e["name"] == "thread_name" for e in meta)
@@ -227,8 +227,10 @@ def test_trace_schema_valid_on_forced_preemption_run(tmp_path):
     assert any(e["ph"] == "C" and e["name"] == "queue_depth"
                for e in trace["traceEvents"])
     # phase histograms saw the same phases the tracer did
-    assert eng.metrics.phase["decode"].count > 0
-    assert eng.metrics.phase["sample_sync"].count > 0
+    for phase in ("decode_launch", "sample_sync", "commit"):
+        assert eng.metrics.phase[phase].count == sum(
+            1 for e in trace["traceEvents"]
+            if e["name"] == phase and e["ph"] == "B")
 
 
 def test_trace_validator_rejects_malformed_traces():
@@ -303,8 +305,9 @@ def test_prometheus_text_round_trip():
     m.on_first_token(0, now=0.2)
     m.on_step(queue_depth=3, busy_slots=1, slots=2, block_utilization=0.5,
               now=0.3)
-    m.on_phase("decode", 0.01)
-    m.on_step_time(0.02, ema=0.02, drift=0.0)
+    m.on_phase("decode_launch", 0.01)
+    m.on_step_time(0.02)
+    m.on_compiles(2)
     m.on_finish(0, n_tokens=4, now=0.5, reason="length")
     text = prometheus_text(m)
     parsed = parse_prometheus_text(text)      # raises on any malformed line
@@ -316,10 +319,13 @@ def test_prometheus_text_round_trip():
     assert parsed["repro_serving_queue_depth_count"][0][1] == "1"
     cums = [int(v) for lab, v in buckets]
     assert cums == sorted(cums)               # cumulative => nondecreasing
-    # a fresh registry renders gauges-without-data as NaN, still parseable
+    assert parsed["repro_serving_step_time_s_count"][0][1] == "1"
+    assert parsed["repro_serving_compiles_total"][0][1] == "2.0"
+    # a fresh registry renders its counter and histograms, still parseable
     empty = prometheus_text(ServingMetrics())
     parsed_empty = parse_prometheus_text(empty)
-    assert parsed_empty["repro_serving_step_time_ema_s"][0][1] == "NaN"
+    assert parsed_empty["repro_serving_compiles_total"][0][1] == "0.0"
+    assert parsed_empty["repro_serving_step_time_s_count"][0][1] == "0"
 
 
 def test_prometheus_labels():
@@ -351,28 +357,8 @@ def test_snapshot_writer_cadence_and_atomicity(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# StepMonitor drift gauge through the facade
+# the engine's step-time and phase histograms and its compile counter
 # ---------------------------------------------------------------------------
-
-def test_step_monitor_drift_exported_as_telemetry():
-    mon = StepMonitor(alpha=1.0, drift_threshold=0.25, min_steps=2)
-    m = ServingMetrics()
-    for _ in range(2):                        # establish the baseline
-        trig = mon.update(0.010)
-        m.on_step_time(0.010, ema=mon.ema, drift=mon.drift_fraction(),
-                       triggered=trig)
-    sig = m.window_signals(now=0.0)
-    assert sig["step_time_ema_s"] == pytest.approx(0.010)
-    assert sig["step_time_drift"] == pytest.approx(0.0)
-    assert sig["replan_triggers"] == 0
-    trig = mon.update(0.020)                  # 2x slower: drift trips
-    assert trig
-    m.on_step_time(0.020, ema=mon.ema, drift=mon.drift_fraction(),
-                   triggered=trig)
-    sig = m.window_signals(now=0.0)
-    assert sig["replan_triggers"] == 1
-    assert m.step_time.count == 3
-
 
 def test_engine_runs_step_monitor_and_phase_histograms():
     arch, reqs, slots, max_len = scenario_requests("tiny/base")
@@ -383,12 +369,18 @@ def test_engine_runs_step_monitor_and_phase_histograms():
     eng.generate([Request(id=rid, prompt=p.copy(), max_new_tokens=mn)
                   for rid, p, mn in reqs])
     s = eng.metrics.summary()
-    assert eng.step_monitor.steps == s["engine_steps"] > 0
-    assert s["step_time"]["count"] == s["engine_steps"]
-    assert s["window"]["step_time_ema_s"] is not None
-    assert s["phases"]["prefill"]["count"] == s["prefill_chunks"]
-    assert s["phases"]["decode"]["count"] == s["decode_steps"]
+    assert s["step_time"]["count"] == s["engine_steps"] > 0
+    # a new engine's first steps trace and compile its paged steps
+    assert eng.metrics.compiles.value >= 1
+    assert "step_time_ema_s" not in s["window"]
+    assert s["phases"]["admission"]["count"] == s["engine_steps"]
+    assert s["phases"]["prefill_launch"]["count"] == s["prefill_chunks"]
+    assert s["phases"]["prefill_sync"]["count"] == len(reqs)
+    assert s["phases"]["decode_launch"]["count"] == s["decode_steps"]
     assert s["phases"]["sample_sync"]["count"] == s["decode_steps"]
+    # one commit after each prefill chunk and one at each step's tail
+    assert s["phases"]["commit"]["count"] == \
+        s["engine_steps"] + s["prefill_chunks"]
     # live scheduler/cache references surfaced through the facade
     assert s["scheduler"]["admitted"] >= len(reqs)
     assert s["cache"]["num_blocks"] == eng.cache.cfg.num_blocks
