@@ -49,8 +49,11 @@ def _q_heads_ok(arch: ArchConfig, mesh: MeshShape) -> bool:
 
 def _kv_heads_ok(arch: ArchConfig, mesh: MeshShape) -> bool:
     """wk/wv shard iff n_kv_heads % model == 0.  When false they stay
-    replicated (tiny: D x kv_dim) and layers._expand_kv broadcasts the
-    replicated k/v into the q-head-sharded layout."""
+    replicated (tiny: D x kv_dim).  The contiguous-cache path
+    (layers._sdpa) then broadcasts the replicated k/v into the q-head-sharded
+    layout with layers._expand_kv; the paged path (layers._paged_sdpa)
+    instead reshapes q's head axis to (Hkv, G), which lines up with the
+    pool's kv-head axis when that axis shards."""
     return _div(min(arch.n_kv_heads, arch.n_heads), mesh.model)
 
 

@@ -140,9 +140,11 @@ def init_attention(key, cfg: AttnConfig, dtype=jnp.float32) -> Params:
 def _expand_kv(t: Array, n_heads: int) -> Array:
     """(B,T,Hkv,D) -> (B,T,H,D) by broadcasting each kv head over its q-group.
 
-    Broadcast-then-reshape keeps the head axis shardable over `model` to the
-    same degree as q's head axis (the kv source is replicated when
-    Hkv < mesh model size — see core/sharding.py)."""
+    Used by the contiguous-cache :func:`_sdpa` only; the paged path
+    (:func:`_paged_sdpa`) contracts each kv head with its own q-group and
+    never materialises the copy.  Broadcast-then-reshape keeps the head axis
+    shardable over `model` to the same degree as q's head axis (the kv
+    source is replicated when Hkv < mesh model size — see core/sharding.py)."""
     B, T, Hkv, D = t.shape
     group = n_heads // Hkv
     t = jnp.broadcast_to(t[:, :, :, None, :], (B, T, Hkv, group, D))
@@ -226,21 +228,27 @@ def _sdpa(q: Array, k: Array, v: Array, *, causal: bool, scale: float,
 
 def _paged_sdpa(q: Array, k: Array, v: Array, *, scale: float,
                 q_pos: Array, kv_len: Array) -> Array:
-    """SDPA with *per-sequence* causal masks: q_pos (B,S), kv_len (B,).
+    """SDPA with *per-sequence* causal masks: q (B,S,H,D), k/v (B,T,Hkv,D)
+    with Hkv | H, q_pos (B,S), kv_len (B,).
 
-    Masked entries contribute exactly-zero probability (exp underflows), so
-    the result is bitwise identical to the contiguous-cache decode path on
-    the unmasked prefix — the greedy-parity test in tests/test_serving.py
-    relies on this."""
+    Each kv head is contracted with its own group of G = H/Hkv query heads
+    (G = 1 is MHA), so the gathered K/V is read once at Hkv heads and never
+    copied to H; the products and their sums over D are those of the
+    broadcast form.  Masked entries contribute exactly-zero probability (exp
+    underflows), so the result equals the contiguous-cache decode path on
+    the unmasked prefix (on CPU: bitwise in bf16, to float rounding in
+    float32).  The greedy-parity test in tests/test_serving.py relies on
+    this."""
     B, S, H, D = q.shape
-    T = k.shape[1]
-    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
+    T, Hkv = k.shape[1], k.shape[2]
+    q = q.reshape(B, S, Hkv, H // Hkv, D)      # kv head k meets q group k
+    logits = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32) * scale
     kp = jnp.arange(T)
     mask = q_pos[:, :, None] >= kp[None, None, :]            # (B,S,T) causal
     mask = mask & (kp[None, None, :] < kv_len[:, None, None])
-    logits = jnp.where(mask[:, None], logits, -1e30)
+    logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhst,bthd->bshd", probs, v)
+    return jnp.einsum("bkgst,btkd->bskgd", probs, v).reshape(B, S, H, D)
 
 
 def paged_flat_indices(positions: Array, seq: int, block_tables: Array,
@@ -314,8 +322,7 @@ def paged_attention(p: Params, cfg: AttnConfig, x: Array, *,
     gv = cv[block_tables].reshape(B, T, Hkv, D).astype(q.dtype)
     scale = cfg.softmax_scale or (1.0 / math.sqrt(cfg.head_dim))
     kv_len = positions + (new_lens if new_lens is not None else S)
-    out = _paged_sdpa(q, _expand_kv(gk, cfg.n_heads), _expand_kv(gv, cfg.n_heads),
-                      scale=scale, q_pos=qp, kv_len=kv_len)
+    out = _paged_sdpa(q, gk, gv, scale=scale, q_pos=qp, kv_len=kv_len)
     y = dense(p["wo"], out.reshape(B, S, cfg.q_dim))
     return y, {"k": ck, "v": cv}
 
