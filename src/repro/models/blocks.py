@@ -231,6 +231,20 @@ def init_paged_block_cache(kind: str, arch: ArchConfig, num_blocks: int,
 # apply
 # ---------------------------------------------------------------------------
 
+def _layer_of(cache, layer):
+    return jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
+        cache)
+
+
+def _put_layer(stacked, layer, old, new):
+    """Write ``new`` back at ``layer`` of ``stacked``; a leaf returned as it
+    was read (a read-only cache) is not written."""
+    return jax.tree.map(
+        lambda s, o, n: s if n is o else jax.lax.dynamic_update_index_in_dim(
+            s, n.astype(s.dtype), layer, 0), stacked, old, new)
+
+
 def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 x0: Optional[Array] = None,
                 cross_input: Optional[Array] = None,
@@ -240,10 +254,24 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 block_tables: Optional[Array] = None,
                 new_lens: Optional[Array] = None,
                 slot_ids: Optional[Array] = None,
+                layer: Optional[Array] = None,
                 impl: str = "xla"):
     """-> (x, new_cache, aux_loss).  ``block_tables`` selects the paged-KV
     decode path for attn-family kinds; ``slot_ids`` selects the slot-state
-    pool path for mamba2 / cross_attn (see serving/cache_manager.py)."""
+    pool path for mamba2 / cross_attn (see serving/cache_manager.py).
+
+    With ``layer`` (an int32 scalar) ``cache`` is the block's cache stacked
+    over the segment's layers — the scan carry of
+    transformer._apply_segment — and the stacked cache is returned: serving
+    pools are read and written in place at (layer, row); contiguous (wave)
+    caches run on this layer's slice, written back at ``layer``."""
+    if layer is not None and block_tables is None and slot_ids is None:
+        c = _layer_of(cache, layer)
+        x, nc, aux = apply_block(p, kind, arch, x, x0=x0,
+                                 cross_input=cross_input, shared=shared,
+                                 cache=c, positions=positions,
+                                 new_lens=new_lens, impl=impl)
+        return x, _put_layer(cache, layer, c, nc), aux
     aux = ZERO
     if (block_tables is not None or slot_ids is not None) and \
             kind not in ("attn", "moe_attn", "mamba2", "cross_attn",
@@ -256,7 +284,7 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
         h, new_cache = L.attention(p["attn"], cfg, norm_apply(arch, p["norm1"], x),
                                    cache=cache, positions=positions,
                                    block_tables=block_tables,
-                                   new_lens=new_lens, impl=impl)
+                                   new_lens=new_lens, layer=layer, impl=impl)
         x = x + h
         if kind == "moe_attn":
             h, aux = MOE.moe(p["moe"], moe_cfg_for(arch),
@@ -271,7 +299,7 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 p["attn"], mla_cfg_for(arch),
                 norm_apply(arch, p["norm1"], x), cache=cache,
                 positions=positions, block_tables=block_tables,
-                new_lens=new_lens)
+                new_lens=new_lens, layer=layer)
         else:
             h, new_cache = MLA.mla_attention(p["attn"], mla_cfg_for(arch),
                                              norm_apply(arch, p["norm1"], x),
@@ -290,7 +318,8 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
             h, new_cache = M2.mamba2_slot(p["mixer"], ssm_cfg_for(arch),
                                           normed, pool=cache,
                                           slot_ids=slot_ids,
-                                          new_lens=new_lens, impl=impl)
+                                          new_lens=new_lens, layer=layer,
+                                          impl=impl)
         else:
             h, new_cache = M2.mamba2(p["mixer"], ssm_cfg_for(arch), normed,
                                      cache=cache, impl=impl)
@@ -301,7 +330,8 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
         if slot_ids is not None:
             # slot-state pool: per-request cross K/V rows are read-only here
             # (written once at admission — transformer.admit_slot)
-            rows = {"k": cache["k"][slot_ids], "v": cache["v"][slot_ids]}
+            at = L.layer_index(layer, slot_ids)
+            rows = {"k": cache["k"][at], "v": cache["v"][at]}
             h, _ = L.attention(p["attn"], cfg,
                                norm_apply(arch, p["norm1"], x),
                                cache=rows, impl=impl)
@@ -325,13 +355,14 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                                  norm_apply(arch, p["norm1"], x),
                                  cache=c_self, positions=positions,
                                  block_tables=block_tables,
-                                 new_lens=new_lens, impl=impl)
+                                 new_lens=new_lens, layer=layer, impl=impl)
         x = x + h
         if slot_ids is not None:
             # slot-state pool: per-request encoder cross K/V rows are
             # read-only here (written once at admission —
             # transformer.admit_slot runs the encoder)
-            rows = {"k": c_cross["k"][slot_ids], "v": c_cross["v"][slot_ids]}
+            at = L.layer_index(layer, slot_ids)
+            rows = {"k": c_cross["k"][at], "v": c_cross["v"][at]}
             h, _ = L.attention(p["xattn"], cross_cfg,
                                norm_apply(arch, p["norm2"], x),
                                cache=rows, impl=impl)
@@ -352,14 +383,14 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
         d2 = 2 * arch.d_model
         cfg = attn_cfg_for(arch, d_model=d2, n_heads=arch.n_heads)
         z = jnp.concatenate([x, x0], axis=-1)
-        # block_tables route to the per-application paged pool (the cache
-        # passed here is this application's slice of the repeat-stacked
-        # pool, so weight sharing never mixes two applications' KV)
+        # block_tables route to the per-application paged pool (``layer``
+        # picks this application's pool out of the repeat-stacked ones, so
+        # weight sharing never mixes two applications' KV)
         h, new_cache = L.attention(shared["attn"], cfg,
                                    norm_apply(arch, shared["norm1"], z),
                                    cache=cache, positions=positions,
                                    block_tables=block_tables,
-                                   new_lens=new_lens, impl=impl)
+                                   new_lens=new_lens, layer=layer, impl=impl)
         z = z + h
         z = z + L.mlp(shared["mlp"], norm_apply(arch, shared["norm2"], z), arch.act)
         return x + L.dense(p["app_proj"], z), new_cache, aux

@@ -277,16 +277,52 @@ def paged_flat_indices(positions: Array, seq: int, block_tables: Array,
     return qp, flat
 
 
+def layer_index(layer: Optional[Array], *rows: Array) -> tuple:
+    """Index tuple for ``rows`` of a pool, or, with ``layer``, of that layer
+    of a stacked pool (L, ...): the layer index is broadcast beside the row
+    indices, so that reading or writing the rows is one gather or one
+    scatter and never a slice of the layer's whole pool."""
+    if layer is None:
+        return rows
+    return (jnp.broadcast_to(layer, rows[0].shape),) + rows
+
+
+def paged_write_gather(pool: Array, new: Array, flat: Array,
+                       block_tables: Array,
+                       layer: Optional[Array] = None) -> tuple[Array, Array]:
+    """Write ``new`` (N, ...) at the flat row indices ``flat`` (N,) (block *
+    block_size + offset, from :func:`paged_flat_indices`) of a block pool,
+    then gather each sequence's pages back in logical order: -> (pool,
+    (B, max_blocks * block_size, ...)).
+
+    ``pool`` is one layer's (NB, BS, ...) pool, or with ``layer`` the
+    stacked (L, NB, BS, ...) pools that transformer._apply_segment carries
+    through its layer scan: the write is then one scatter at (layer, block,
+    offset) and the read one gather at (layer, block), so XLA updates the
+    carry in place and no layer's pool is copied out of the stack."""
+    BS = pool.shape[1 if layer is None else 2]
+    blk, off = jnp.divmod(flat, BS)
+    pool = pool.at[layer_index(layer, blk, off)].set(new.astype(pool.dtype))
+    view = pool[layer_index(layer, block_tables)]      # (B, MB, BS, ...)
+    B, MB = block_tables.shape
+    return pool, view.reshape((B, MB * BS) + view.shape[3:])
+
+
 def paged_attention(p: Params, cfg: AttnConfig, x: Array, *,
                     cache: Params, positions: Array,
                     block_tables: Array,
-                    new_lens: Optional[Array] = None) -> tuple[Array, Params]:
+                    new_lens: Optional[Array] = None,
+                    layer: Optional[Array] = None) -> tuple[Array, Params]:
     """Self-attention over a block-paged KV pool (vLLM-style paged KV).
 
     cache: {"k": (NB, BS, Hkv, D), "v": ...} — a *physical block pool* shared
     by every request; ``block_tables`` (B, max_blocks) int32 maps each
     sequence's logical block j to a physical block (block 0 is the reserved
-    null block — idle batch slots point every entry there).  ``positions``
+    null block — idle batch slots point every entry there).  With ``layer``
+    the pools are the stacked (L, NB, BS, Hkv, D) scan carry of
+    transformer._apply_segment and this call is layer ``layer``: the new
+    rows are written into the stack in place and the block tables gathered
+    from it (:func:`paged_write_gather`).  ``positions``
     (B,) int32 is each sequence's token count before this call; the S new
     tokens are written at logical positions positions[b]..positions[b]+S-1
     and attention runs over the gathered logical view with per-sequence
@@ -297,7 +333,6 @@ def paged_attention(p: Params, cfg: AttnConfig, x: Array, *,
     repro/serving/paged_cache.py.
     """
     B, S, _ = x.shape
-    NB, BS, Hkv, D = cache["k"].shape
     q = dense(p["wq"], x).reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = dense(p["wk"], x).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = dense(p["wv"], x).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
@@ -306,23 +341,22 @@ def paged_attention(p: Params, cfg: AttnConfig, x: Array, *,
         k = rmsnorm(p["k_norm"], k)
     # scatter new k/v into their pages (flat row index = block * BS + offset;
     # overrun/padded writes divert to the null block — see paged_flat_indices)
+    BS = cache["k"].shape[-3]
     qp, flat = paged_flat_indices(positions, S, block_tables, BS,
                                   new_lens=new_lens)
     if cfg.use_rope:
         q = apply_rope(q, qp, cfg.rope_theta)
         k = apply_rope(k, qp, cfg.rope_theta)
     flat = flat.reshape(-1)                                  # (B*S,)
-    ck = cache["k"].reshape(NB * BS, Hkv, D).at[flat].set(
-        k.astype(cache["k"].dtype).reshape(B * S, Hkv, D)).reshape(NB, BS, Hkv, D)
-    cv = cache["v"].reshape(NB * BS, Hkv, D).at[flat].set(
-        v.astype(cache["v"].dtype).reshape(B * S, Hkv, D)).reshape(NB, BS, Hkv, D)
-    # gather each sequence's pages back into logical order
-    T = block_tables.shape[1] * BS
-    gk = ck[block_tables].reshape(B, T, Hkv, D).astype(q.dtype)
-    gv = cv[block_tables].reshape(B, T, Hkv, D).astype(q.dtype)
+    rows = (B * S, cfg.n_kv_heads, cfg.head_dim)
+    ck, gk = paged_write_gather(cache["k"], k.reshape(rows), flat,
+                                block_tables, layer)
+    cv, gv = paged_write_gather(cache["v"], v.reshape(rows), flat,
+                                block_tables, layer)
     scale = cfg.softmax_scale or (1.0 / math.sqrt(cfg.head_dim))
     kv_len = positions + (new_lens if new_lens is not None else S)
-    out = _paged_sdpa(q, gk, gv, scale=scale, q_pos=qp, kv_len=kv_len)
+    out = _paged_sdpa(q, gk.astype(q.dtype), gv.astype(q.dtype), scale=scale,
+                      q_pos=qp, kv_len=kv_len)
     y = dense(p["wo"], out.reshape(B, S, cfg.q_dim))
     return y, {"k": ck, "v": cv}
 
@@ -333,6 +367,7 @@ def attention(p: Params, cfg: AttnConfig, x: Array, *,
               positions: Optional[Array] = None,
               block_tables: Optional[Array] = None,
               new_lens: Optional[Array] = None,
+              layer: Optional[Array] = None,
               impl: str = "xla") -> tuple[Array, Optional[Params]]:
     """Self- or cross-attention.
 
@@ -340,12 +375,14 @@ def attention(p: Params, cfg: AttnConfig, x: Array, *,
     k/v written at ``pos``; returns updated cache.  For cross-attention the
     cache holds precomputed encoder K/V and is not updated.  When
     ``block_tables`` is given the cache is a paged block pool instead and
-    dispatches to :func:`paged_attention` (per-sequence positions).
+    dispatches to :func:`paged_attention` (per-sequence positions; with
+    ``layer``, layer ``layer`` of the stacked pools).
     """
     if block_tables is not None:
         assert cache is not None and positions is not None
         return paged_attention(p, cfg, x, cache=cache, positions=positions,
-                               block_tables=block_tables, new_lens=new_lens)
+                               block_tables=block_tables, new_lens=new_lens,
+                               layer=layer)
     B, S, _ = x.shape
     src = kv_input if kv_input is not None else x
     q = dense(p["wq"], x).reshape(B, S, cfg.n_heads, cfg.head_dim)

@@ -278,6 +278,7 @@ def mamba2(p: Params, cfg: Mamba2Config, x: Array, *,
 def mamba2_slot(p: Params, cfg: Mamba2Config, x: Array, *,
                 pool: Params, slot_ids: Array,
                 new_lens: Optional[Array] = None,
+                layer: Optional[Array] = None,
                 impl: str = "xla") -> tuple[Array, Params]:
     """Serving path over a *slot-indexed state pool* (continuous batching).
 
@@ -286,16 +287,19 @@ def mamba2_slot(p: Params, cfg: Mamba2Config, x: Array, *,
     the last row is the reserved null slot (the slot-state analogue of the
     paged-KV null block).  ``slot_ids`` (B,) maps each batch row to its pool
     row; inactive batch rows point at the null slot, so their garbage
-    updates scatter into scratch that no live request ever reads.
+    updates scatter into scratch that no live request ever reads.  With
+    ``layer`` the pool is the stacked (L, slots+1, ...) scan carry and the
+    rows are those of layer ``layer`` (layers.layer_index).
 
     Gather rows -> run the exact wave-path recurrence/chunked scan on them
     (decode when S==1 and new_lens is None, chunk-prefill otherwise, with
     the SSM state carried as h0 across chunks) -> scatter updated rows back.
     """
-    rows = jax.tree.map(lambda t: t[slot_ids], pool)
+    at = L.layer_index(layer, slot_ids)
+    rows = jax.tree.map(lambda t: t[at], pool)
     decode = x.shape[1] == 1 and new_lens is None
     y, new_rows = mamba2(p, cfg, x, cache=rows,
                          new_lens=None if decode else new_lens, impl=impl)
     new_pool = jax.tree.map(
-        lambda t, n: t.at[slot_ids].set(n.astype(t.dtype)), pool, new_rows)
+        lambda t, n: t.at[at].set(n.astype(t.dtype)), pool, new_rows)
     return y, new_pool

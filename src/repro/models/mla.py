@@ -142,17 +142,20 @@ def _attend(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, p, *,
 def mla_paged_attention(p: Params, cfg: MLAConfig, x: Array, *,
                         cache: Params, positions: Array,
                         block_tables: Array,
-                        new_lens: Optional[Array] = None
+                        new_lens: Optional[Array] = None,
+                        layer: Optional[Array] = None
                         ) -> tuple[Array, Params]:
     """Latent attention over block-paged (c_kv, k_rope) pools — the MLA
     analogue of layers.paged_attention, same flat-index scheme: new latents
     scatter at block_tables[b, pos // BS] * BS + pos % BS, out-of-table and
     padded-row writes divert to the null block, and attention runs over the
-    gathered logical view with per-sequence causal/length masks.  Masked
-    entries contribute exactly-zero probability, so greedy decode is
-    token-identical to the contiguous-cache path on the unmasked prefix."""
+    gathered logical view with per-sequence causal/length masks.  With
+    ``layer`` the pools are the stacked scan carry, written and gathered in
+    place at that layer (layers.paged_write_gather).  Masked entries
+    contribute exactly-zero probability, so greedy decode is token-identical
+    to the contiguous-cache path on the unmasked prefix."""
     B, S, _ = x.shape
-    NB, BS, r = cache["c_kv"].shape
+    BS = cache["c_kv"].shape[-2]
     kv = L.dense(p["wkv_a"], x)
     c_kv = L.rmsnorm(p["kv_norm"], kv[..., : cfg.kv_lora_rank])
     k_rope_new = kv[..., cfg.kv_lora_rank:]
@@ -161,15 +164,11 @@ def mla_paged_attention(p: Params, cfg: MLAConfig, x: Array, *,
     k_rope_new = L.apply_rope(k_rope_new[:, :, None, :], qp,
                               cfg.rope_theta)[:, :, 0, :]
     flat = flat.reshape(-1)
-    cc = cache["c_kv"].reshape(NB * BS, r).at[flat].set(
-        c_kv.astype(cache["c_kv"].dtype).reshape(B * S, r)).reshape(NB, BS, r)
-    dr = cache["k_rope"].shape[-1]
-    cr = cache["k_rope"].reshape(NB * BS, dr).at[flat].set(
-        k_rope_new.astype(cache["k_rope"].dtype).reshape(B * S, dr)
-        ).reshape(NB, BS, dr)
-    T = block_tables.shape[1] * BS
-    g_ckv = cc[block_tables].reshape(B, T, r)
-    g_rope = cr[block_tables].reshape(B, T, dr)
+    cc, g_ckv = L.paged_write_gather(cache["c_kv"], c_kv.reshape(B * S, -1),
+                                     flat, block_tables, layer)
+    cr, g_rope = L.paged_write_gather(cache["k_rope"],
+                                      k_rope_new.reshape(B * S, -1), flat,
+                                      block_tables, layer)
     q_nope, q_rope = _project_q(p, cfg, x, qp)
     kv_len = positions + (new_lens if new_lens is not None else S)
     ctx = _attend(cfg, q_nope, q_rope, g_ckv, g_rope, p,

@@ -103,7 +103,8 @@ def _init_segment(key, blocks: tuple, repeat: int, arch: ArchConfig, dt) -> Para
 
 def init_cache(arch: ArchConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> list:
-    """Per-segment stacked caches (leading repeat axis)."""
+    """Per-segment stacked caches (leading repeat axis; the carry of the
+    segment's layer scan, see _apply_segment)."""
     caches = []
     for seg in arch.pattern:
         def one(_):
@@ -119,6 +120,10 @@ def init_cache(arch: ArchConfig, batch: int, max_len: int,
 def init_paged_cache(arch: ArchConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16, *, slots: int = 0) -> list:
     """Per-segment stacked serving cache pools (leading repeat axis).
+
+    Each segment's stack is the carry of its layer scan (_apply_segment),
+    updated in place at each layer's index: a step writes its new rows and
+    nothing else of the pools.
 
     Two state classes, side by side (serving/cache_manager.py is the host
     side of both):
@@ -270,36 +275,52 @@ def _apply_segment(seg_params, blocks, arch, x, *, seg_cache=None, x0=None,
                    unroll: int = 1, remat: str = "none", act_sharding=None):
     """Scan the segment body over its repeat axis.  ``remat`` applies
     per-layer activation checkpointing inside the scan (the standard
-    scan-over-layers + remat pattern — O(1) activation memory in depth)."""
+    scan-over-layers + remat pattern — O(1) activation memory in depth).
+
+    With ``seg_cache`` the segment's stacked cache is the scan *carry*, not
+    a scanned input: each layer gets its index as the scan input and its
+    blocks update the carry in place at that index (B.apply_block's
+    ``layer``), so a step writes only its new rows and never slices a
+    layer's pool out, writes it back whole into a stacked result, or copies
+    that result after the loop.  The carry is the step's donated cache
+    argument (runtime/steps.py STEP_DONATION)."""
     has_cache = seg_cache is not None
 
     def body(carry, xs):
-        x, aux = carry
+        if has_cache:
+            (x, aux, cache), (p_stack, layer) = carry, xs
+        else:
+            (x, aux), p_stack = carry, xs
+            cache = layer = None
         x = _constrain(x, act_sharding)
-        p_stack, c_stack = xs if has_cache else (xs, None)
-        new_caches = {}
         for i, kind in enumerate(blocks):
             bi = f"b{i}"
-            c = c_stack[bi] if has_cache else None
             x, nc, a = B.apply_block(
                 p_stack[bi], kind, arch, x, x0=x0, cross_input=cross_input,
-                shared=shared, cache=c, positions=positions,
-                block_tables=block_tables, new_lens=new_lens,
-                slot_ids=slot_ids, impl=impl)
+                shared=shared, cache=cache[bi] if has_cache else None,
+                positions=positions, block_tables=block_tables,
+                new_lens=new_lens, slot_ids=slot_ids, layer=layer, impl=impl)
             if has_cache:
-                new_caches[bi] = nc
+                cache = {**cache, bi: nc}
             aux = aux + a
         x = _constrain(x, act_sharding)
-        return (x, aux), (new_caches if has_cache else B.ZERO)
+        if has_cache:
+            return (x, aux, cache), None
+        return (x, aux), B.ZERO
 
-    if remat != "none" and not has_cache:
-        policy = (None if remat == "full" else
-                  jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-        body = jax.checkpoint(body, policy=policy)
-
-    xs = (seg_params, seg_cache) if has_cache else seg_params
-    (x, aux), ys = jax.lax.scan(body, (x, B.ZERO), xs, unroll=unroll)
-    return x, aux, (ys if has_cache else None)
+    if not has_cache:
+        if remat != "none":
+            policy = (None if remat == "full" else
+                      jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+            body = jax.checkpoint(body, policy=policy)
+        (x, aux), _ = jax.lax.scan(body, (x, B.ZERO), seg_params,
+                                   unroll=unroll)
+        return x, aux, None
+    depth = jax.tree.leaves(seg_params)[0].shape[0]
+    (x, aux, seg_cache), _ = jax.lax.scan(
+        body, (x, B.ZERO, seg_cache),
+        (seg_params, jnp.arange(depth, dtype=jnp.int32)), unroll=unroll)
+    return x, aux, seg_cache
 
 
 def lm_apply(params: Params, arch: ArchConfig, tokens: Optional[Array] = None, *,

@@ -26,13 +26,20 @@ captured from the pre-shim wave Server (see gen_serving_goldens.py).
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import pathlib
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import (ArchConfig, EncoderSpec, MLASpec, MoESpec,
                                 Segment, SSMSpec)
+from repro.models import transformer as T
+from repro.runtime import steps as ST
+from repro.serving.sampling import make_sampler
 
 GOLDENS_PATH = pathlib.Path(__file__).resolve().parent / "goldens_serving.json"
 
@@ -149,3 +156,52 @@ def load_goldens(name: str) -> dict[int, list[int]]:
     with open(GOLDENS_PATH) as f:
         data = json.load(f)
     return {int(k): v for k, v in data["scenarios"][name].items()}
+
+
+# --- compiled structure of the paged decode -----------------------------------
+
+# one attention segment of three layers, for compiles whose pools outweigh
+# everything else the decode holds
+STRUCT_ARCH = dataclasses.replace(TINY, n_layers=3,
+                                  pattern=(Segment(("attn",), 3),))
+
+OP_LINE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\w+)\[([\d,]*)\]"
+                     r"(?:\{[^}]*\})?\s+([\w\-]+)\(")
+POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def pool_moves(hlo: str, pool_shape: tuple, depth: int) -> list[str]:
+    """Instructions of a compiled program (fused computations included)
+    that copy, slice or write back a whole pool: their result has one
+    layer's pool shape or the stacked one."""
+    shapes = {",".join(map(str, pool_shape)),
+              ",".join(map(str, (depth,) + pool_shape))}
+    return [line.strip() for line in hlo.splitlines()
+            if (m := OP_LINE.match(line)) and m.group(3) in POOL_MOVES
+            and m.group(2) in shapes]
+
+
+def paged_decode_compiled(arch, *, num_blocks, block_size, slots, table_len,
+                          pool_dtype=jnp.float32, sharding=None):
+    """-> (compiled paged decode with the fused sampler, bytes of the whole
+    cache) for ``slots`` rows; arguments are shapes on ``sharding``."""
+    def sds(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+    params = sds(jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0),
+                                                  arch)))
+    pools = sds(jax.eval_shape(lambda: T.init_paged_cache(
+        arch, num_blocks, block_size, pool_dtype)))
+
+    def vec(*shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    fn = ST.jit_step("paged_decode", ST.make_paged_decode_step(
+        arch, sampler=make_sampler(arch.vocab)))
+    compiled = fn.lower(params, pools, vec(slots, 1), vec(slots),
+                        vec(slots, table_len), vec(slots),
+                        vec(slots, dt=jnp.float32), vec(slots),
+                        vec(slots, dt=jnp.float32),
+                        vec(slots, dt=jnp.uint32)).compile()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(pools))
+    return compiled, cache_bytes

@@ -1,14 +1,17 @@
-"""The Pallas kernels compile for a TPU v5e at real widths.
+"""The Pallas kernels compile for a TPU v5e at real widths, and the paged
+decode updates its KV pools in place there.
 
-Each test compiles one kernel ahead of time for one chip of a described
-``v5e:2x2`` topology (no chip attached, nothing runs) and checks that the
-program holds the Mosaic kernel.  This catches what interpret mode cannot:
-block shapes off the TPU tiling, more VMEM than a kernel may use.
+Each test compiles one program ahead of time for one chip of a described
+``v5e:2x2`` topology (no chip attached, nothing runs).  The kernel tests
+check that the program holds the Mosaic kernel.  This catches what
+interpret mode cannot: block shapes off the TPU tiling, more VMEM than a
+kernel may use.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -19,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import flash_attention as FA
 from repro.kernels import rmsnorm as RN
 from repro.kernels import ssd_scan as SSD
+from serving_fixtures import STRUCT_ARCH, paged_decode_compiled, pool_moves
 
 
 @pytest.fixture(scope="module")
@@ -74,3 +78,25 @@ def test_rmsnorm_compiles_for_v5e(one_chip, no_compile_cache):
     hlo = _compile(lambda x, s: RN.rmsnorm(x, s, interpret=False), one_chip,
                    ((4096, 4096), jnp.bfloat16), ((4096,), jnp.bfloat16))
     assert "tpu_custom_call" in hlo
+
+
+def test_paged_decode_updates_pools_in_place_on_v5e(one_chip,
+                                                    no_compile_cache):
+    """The bf16 paged decode writes its new K/V rows into the stacked pools
+    it carries through the layer scan: no copy, slice or write-back of a
+    layer's pool or of the stack, and the cache aliased input to output.
+    KV heads of 8 x 128 as qwen3-8b's, and pools too large for the chip's
+    on-core memory (a small one is staged there whole)."""
+    arch = dataclasses.replace(STRUCT_ARCH, n_heads=16, n_kv_heads=8,
+                               head_dim=128)
+    blocks, block_size = 2048, 16
+    compiled, cache_bytes = paged_decode_compiled(
+        arch, num_blocks=blocks, block_size=block_size, slots=8,
+        table_len=8, pool_dtype=jnp.bfloat16, sharding=one_chip)
+    pool_shape = (blocks, block_size, arch.n_kv_heads,
+                  arch.resolved_head_dim)
+    assert pool_moves(compiled.as_text(), pool_shape,
+                      arch.pattern[0].repeat) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 2     # one stacked pool
