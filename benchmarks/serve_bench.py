@@ -1,22 +1,11 @@
-"""Serving benchmark: legacy wave-shim client pattern vs direct continuous
-engine on one synthetic trace.
+"""Serving benchmark: the continuous engine on synthetic traces (CPU).
 
-The wave decode path is gone — ``runtime.server.Server`` is a compatibility
-shim over ``ContinuousBatchingEngine`` — so the "wave" rows now measure the
-*legacy client pattern through the shim*: up to ``slots`` requests submitted,
-``run_until_drained()``, repeat.  Requests arriving mid-drain wait for the
-whole batch to finish, which is exactly the admission latency the engine's
-``step()`` loop (continuous rows) removes; the speedup column quantifies
-what retiring the wave API is worth, not two different decode kernels.
-
-Both rows see the same requests in the same arrival order.  Results
-(throughput, TTFT/TPOT with p50/p95/p99, per-phase duration breakdown,
-latency, occupancy, preemptions, block utilization) land in
-BENCH_serving.json — one row per architecture,
-covering every serving cache class: attention-only (qwen3), pure-SSM
-slot-state (mamba2), zamba2's weight-shared paged block and whisper's
-encoder-decoder (the two archs the engine could not serve before the wave
-path was retired).
+One row per architecture serves a Poisson trace through
+``ContinuousBatchingEngine`` and records throughput, TTFT/TPOT with
+p50/p95/p99, per-phase duration breakdown, latency, occupancy,
+preemptions and block utilization in BENCH_serving.json, covering every
+serving cache class: attention-only (qwen3), pure-SSM slot-state (mamba2),
+zamba2's weight-shared paged block and whisper's encoder-decoder.
 
 A final ``prefix_sharing`` row measures cross-request shared-prefix block
 reuse on the attention arch: a Poisson trace whose prompts share a long
@@ -58,7 +47,6 @@ import os
 import pathlib
 import sys
 import time
-import warnings
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
@@ -68,7 +56,6 @@ import numpy as np
 from repro.configs import ARCHS, reduce_for_smoke
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
-from repro.runtime.server import Request as WaveRequest, Server
 from repro.serving import (ContinuousBatchingEngine, Request, SamplingParams,
                            ServingMetrics, init_params)
 
@@ -84,9 +71,7 @@ def _ms(x) -> str:
 
 def make_trace(n: int, rate_hz: float, vocab: int, seed: int = 0):
     """[(arrival_s, prompt, max_new)] — Poisson arrivals, mixed prompt *and*
-    output lengths (a drain-the-batch client stalls every later arrival
-    until its slowest request finishes, so length variance is precisely
-    what continuous admission reclaims)."""
+    output lengths."""
     rng = np.random.default_rng(seed)
     t, trace = 0.0, []
     for _ in range(n):
@@ -137,62 +122,6 @@ def make_grouped_prefix_trace(n: int, rate_hz: float, vocab: int,
     return trace
 
 
-def bench_wave_shim(arch, params, mesh, trace, *, slots, max_len,
-                    block_size, prefill_chunk):
-    """Legacy client pattern through the Server shim: submit up to `slots`
-    arrived requests, drain, repeat.  (The shim no longer needs the old
-    equal-length-prompts-per-wave padding — the engine prefills each prompt
-    at its own length.)  The underlying engine gets the SAME knobs as the
-    continuous row, so the speedup column isolates the client pattern."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        srv = Server(arch, params, mesh, slots=slots, max_len=max_len,
-                     block_size=block_size, prefill_chunk=prefill_chunk)
-    # warm up the jitted steps so rows measure serving, not compilation
-    srv.submit(WaveRequest(id=len(trace), prompt=np.ones(8, np.int32),
-                           max_new_tokens=2))
-    srv.run_until_drained()
-    srv.completed.clear()
-    srv.engine.metrics = ServingMetrics()
-    pending = list(enumerate(trace))
-    arrival = {i: a for i, (a, _, _) in enumerate(trace)}
-    t0 = time.perf_counter()
-    queue: list[WaveRequest] = []
-    while pending or queue:
-        now = time.perf_counter() - t0
-        while pending and pending[0][1][0] <= now:
-            i, (_, prompt, max_new) = pending.pop(0)
-            queue.append(WaveRequest(id=i, prompt=prompt.copy(),
-                                     max_new_tokens=max_new))
-        if not queue:
-            time.sleep(min(pending[0][1][0] - now, 0.01))
-            continue
-        group, queue = queue[:slots], queue[slots:]
-        for r in group:
-            srv.submit(r)
-        srv.run_until_drained()
-    wall = time.perf_counter() - t0
-    # recompute TTFT/TPOT from trace *arrival* (not shim-submit time) so the
-    # batch-drain queueing cost the legacy API imposes is visible, using the
-    # same ServingMetrics definitions as the continuous rows; engine-level
-    # counters (occupancy, queue depth, preemptions, step counts) carry over
-    # from the real run — they are measurements, not re-derivable
-    em = srv.engine.metrics
-    m = ServingMetrics()
-    m.adopt_step_stats(em)
-    for r in srv.completed:
-        m.on_submit(r.id, t0 + arrival[r.id])
-        m.on_first_token(r.id, em.first_token_t[r.id])
-        m.on_finish(r.id, len(r.out_tokens), em.finish_t[r.id])
-    out = m.summary()
-    out.update(engine="wave-shim", wall_s=wall,
-               tokens_per_sec=out["total_tokens"] / wall,
-               latency_mean_s=float(np.mean(
-                   [em.finish_t[r.id] - (t0 + arrival[r.id])
-                    for r in srv.completed])))
-    return out
-
-
 def bench_continuous(arch, params, mesh, trace, *, slots, max_len,
                      block_size, prefill_chunk, share_prefix=False,
                      sampling_for=None, sanitize=False):
@@ -221,8 +150,8 @@ def bench_continuous(arch, params, mesh, trace, *, slots, max_len,
         now = time.perf_counter() - t0
         while pending and pending[0][1][0] <= now:
             i, (arrival_s, prompt, max_new) = pending.pop(0)
-            # stamp TTFT from trace *arrival* like the wave-shim rows, not
-            # from when the polling loop got around to submitting
+            # stamp TTFT from trace *arrival*, not from when the polling
+            # loop got around to submitting
             eng.submit(Request(id=i, prompt=prompt.copy(),
                                max_new_tokens=max_new,
                                sampling=(sampling_for(i) if sampling_for
@@ -252,24 +181,15 @@ def bench_arch(arch_name, args, mesh):
     row = {"arch": arch.name, "family": arch.family, "trace": {
         "requests": args.requests, "rate_hz": args.rate,
         "prompt_lens": sorted({len(p) for _, p, _ in trace})}}
-    engine_kw = {"block_size": args.block_size,
-                 "prefill_chunk": args.prefill_chunk}
-    for name, fn, kw in [
-        ("wave", bench_wave_shim, engine_kw),
-        ("continuous", bench_continuous,
-         dict(engine_kw, sanitize=args.sanitize)),
-    ]:
-        r = fn(arch, params, mesh, trace, slots=args.slots,
-               max_len=args.max_len, **kw)
-        row[name] = r
-        print(f"[{arch.name}/{r['engine']}] {r['total_tokens']} tokens "
-              f"{r['tokens_per_sec']:.1f} tok/s "
-              f"ttft {_ms(r['ttft_mean_s'])} p95 {_ms(r['ttft_p95_s'])} "
-              f"tpot {_ms(r['tpot_mean_s'])} p95 {_ms(r['tpot_p95_s'])}")
-    row["speedup_tokens_per_sec"] = (
-        row["continuous"]["tokens_per_sec"]
-        / row["wave"]["tokens_per_sec"])
-    print(f"[{arch.name}] speedup {row['speedup_tokens_per_sec']:.2f}x")
+    r = bench_continuous(arch, params, mesh, trace, slots=args.slots,
+                         max_len=args.max_len, block_size=args.block_size,
+                         prefill_chunk=args.prefill_chunk,
+                         sanitize=args.sanitize)
+    row["continuous"] = r
+    print(f"[{arch.name}/{r['engine']}] {r['total_tokens']} tokens "
+          f"{r['tokens_per_sec']:.1f} tok/s "
+          f"ttft {_ms(r['ttft_mean_s'])} p95 {_ms(r['ttft_p95_s'])} "
+          f"tpot {_ms(r['tpot_mean_s'])} p95 {_ms(r['tpot_p95_s'])}")
     return row
 
 

@@ -1,8 +1,7 @@
-"""Continuous serving for the formerly wave-only architectures.
+"""Continuous serving for the hybrid and encoder-decoder architectures.
 
 zamba2 (weight-shared attention block over a Mamba2 backbone) and whisper
-(encoder-decoder) were the last two configs stuck on the retired wave
-Server.  Both now run on ContinuousBatchingEngine:
+(encoder-decoder) run on ContinuousBatchingEngine like every other config:
 
   * zamba2: the shared block's KV pages through a per-application block
     pool (one pool row per application of the shared weights), the Mamba2
@@ -10,7 +9,7 @@ Server.  Both now run on ContinuousBatchingEngine:
   * whisper: each request may carry audio frame embeddings as its
     ``frontend`` — the encoder runs ONCE at admission and every decoder
     layer's cross K/V is written into the request's slot rows; text-only
-    requests decode against zero cross K/V, exactly like the old wave path.
+    requests decode against zero cross K/V.
 
     PYTHONPATH=src python examples/serve_hybrid_archs.py
 """

@@ -12,6 +12,8 @@ Everything here is pure extraction: no thresholds, no verdicts.  The
 engine's geometry conventions are mirrored exactly (prefill is a B=1
 chunk, decode advances every slot, block tables are padded to
 ``max_blocks_per_seq``), so what gets lowered IS what serves.
+launch/dryrun.py lowers its prefill and decode cells through the same
+builders (``step_arguments``, ``build_step_fn``).
 """
 from __future__ import annotations
 

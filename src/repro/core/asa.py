@@ -42,9 +42,6 @@ class SchedulePlan:
     def param_specs(self):
         return SH.param_specs(self.arch, self.assignment, self.mesh)
 
-    def cache_specs(self, batch: int):
-        return SH.cache_specs(self.arch, self.assignment, self.mesh, batch)
-
     def paged_cache_specs(self):
         return SH.paged_cache_specs(self.arch, self.assignment, self.mesh)
 

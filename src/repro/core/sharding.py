@@ -49,11 +49,10 @@ def _q_heads_ok(arch: ArchConfig, mesh: MeshShape) -> bool:
 
 def _kv_heads_ok(arch: ArchConfig, mesh: MeshShape) -> bool:
     """wk/wv shard iff n_kv_heads % model == 0.  When false they stay
-    replicated (tiny: D x kv_dim).  The contiguous-cache path
-    (layers._sdpa) then broadcasts the replicated k/v into the q-head-sharded
-    layout with layers._expand_kv; the paged path (layers._paged_sdpa)
-    instead reshapes q's head axis to (Hkv, G), which lines up with the
-    pool's kv-head axis when that axis shards."""
+    replicated (tiny: D x kv_dim).  Attention (layers._sdpa,
+    layers._paged_sdpa) reshapes q's head axis to (Hkv, G) and never copies
+    k/v to H heads; the kv-head axis lines up with the pool's when that
+    axis shards."""
     return _div(min(arch.n_kv_heads, arch.n_heads), mesh.model)
 
 
@@ -291,63 +290,6 @@ def opt_state_specs(opt_sds, param_specs_tree, mesh: MeshShape):
                          None if extra is None else jax.tree.map(flat_rule, extra))
 
 
-def cache_specs(arch: ArchConfig, assignment: dict[str, Strategy],
-                mesh: MeshShape, batch: int):
-    """Spec tree mirroring init_cache: per-segment stacked block caches."""
-    ba = batch_axes(mesh, batch)
-
-    def kv_time_spec(strat, extra_rank):
-        # (repeat, B, T, ...) — time axis sharded over `model` under MP/HP
-        t_ax = "model" if strat in (Strategy.MP, Strategy.HP) else None
-        return P(None, ba, t_ax, *([None] * extra_rank))
-
-    specs = []
-    for si, seg in enumerate(arch.pattern):
-        seg_spec = {}
-        for bi, kind in enumerate(seg.blocks):
-            if kind in SPLIT_KEYS:
-                comp = f"seg{si}/b{bi}:{kind}.mixer"
-            else:
-                comp = f"seg{si}/b{bi}:{kind}"
-            strat = assignment.get(comp, Strategy.DP)
-            if kind in ("attn", "moe_attn"):
-                seg_spec[f"b{bi}"] = {"k": kv_time_spec(strat, 2),
-                                      "v": kv_time_spec(strat, 2),
-                                      "pos": P(None)}
-            elif kind in ("mla", "mla_dense"):
-                seg_spec[f"b{bi}"] = {"c_kv": kv_time_spec(strat, 1),
-                                      "k_rope": kv_time_spec(strat, 1),
-                                      "pos": P(None)}
-            elif kind == "mamba2":
-                H = (arch.ssm.expand * arch.d_model) // arch.ssm.head_dim
-                h_ax = "model" if (strat in (Strategy.MP, Strategy.HP)
-                                   and _div(H, mesh.model)) else None
-                seg_spec[f"b{bi}"] = {
-                    "conv_x": P(None, ba, None, h_ax),
-                    "conv_b": P(None, ba, None, None),
-                    "conv_c": P(None, ba, None, None),
-                    "ssm": P(None, ba, h_ax, None, None)}
-            elif kind == "cross_attn":
-                seg_spec[f"b{bi}"] = {"k": P(None, ba, None, None, None),
-                                      "v": P(None, ba, None, None, None)}
-            elif kind == "wdec":
-                seg_spec[f"b{bi}"] = {
-                    "self": {"k": kv_time_spec(strat, 2),
-                             "v": kv_time_spec(strat, 2), "pos": P(None)},
-                    "cross": {"k": P(None, ba, None, None, None),
-                              "v": P(None, ba, None, None, None)}}
-            elif kind == "shared_attn":
-                _div(arch.n_heads, mesh.model)   # validates divisibility
-                t_ax = "model" if (strat in (Strategy.MP, Strategy.HP)) else None
-                seg_spec[f"b{bi}"] = {"k": P(None, ba, t_ax, None, None),
-                                      "v": P(None, ba, t_ax, None, None),
-                                      "pos": P(None)}
-            else:
-                seg_spec[f"b{bi}"] = None
-        specs.append(seg_spec)
-    return specs
-
-
 def paged_cache_specs(arch: ArchConfig, assignment: dict[str, Strategy],
                       mesh: MeshShape):
     """Spec tree mirroring init_paged_cache: per-segment stacked pools for
@@ -355,14 +297,13 @@ def paged_cache_specs(arch: ArchConfig, assignment: dict[str, Strategy],
 
     attn-family block pools are (repeat, num_blocks, block_size, Hkv,
     head_dim).  They have no batch axis and their block axis is gathered
-    through block tables every step, so unlike cache_specs the time axis
-    cannot carry the MP shard; instead the kv-head axis shards over `model`
-    (the classic paged-KV layout) whenever the head count divides, else the
-    pool is replicated.
+    through block tables every step, so neither can carry the MP shard;
+    instead the kv-head axis shards over `model` (the classic paged-KV
+    layout) whenever the head count divides, else the pool is replicated.
 
     Slot-state pools have a leading (repeat, slots+1) prefix.  mamba2 state
-    shards its SSM head axis over `model` (mirroring the training-plan cache
-    layout); cross-attn K/V shards its kv-head axis like the attn pools.
+    shards its SSM head axis over `model` under MP/HP when the head count
+    divides; cross-attn K/V shards its kv-head axis like the attn pools.
 
     zamba2's shared block pages a full-MHA pool per application (head axis
     over `model` when n_heads divides); whisper's wdec carries a paged

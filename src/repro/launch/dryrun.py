@@ -18,7 +18,7 @@ jax initializes (jax locks the device count on first init; consequently no
 `from __future__ import annotations` in this module).
 """
 import argparse
-import functools
+import dataclasses
 import json
 import pathlib
 import re
@@ -29,12 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis import ircost as IR
 from repro.configs import ARCHS, SHAPES, get_arch, shape_applicable
 from repro.core import components as C
 from repro.core import sharding as SH
 from repro.core.asa import AdaptiveScheduler
 from repro.launch.mesh import make_production_mesh, mesh_shape_of
-from repro.models import transformer as T
 from repro.optim import optimizers as O
 from repro.runtime import steps as ST
 
@@ -42,6 +42,9 @@ OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
 # archs whose optimizer states only fit with int8 moments (DESIGN.md §7)
 QUANTIZED_OPT = {"arctic-480b", "deepseek-v3-671b"}
+
+# serving cells' page and prompt-chunk sizes (the chat cell's engine)
+SERVE_BLOCK, SERVE_CHUNK = 16, 256
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -193,7 +196,7 @@ def build_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     # seq-sharding the scan carry breaks on SSM families (the conv/scan mix
     # tokens across shard boundaries -> GSPMD gathers); keep batch-only there
     seq_ok = seq_shard and arch.family not in ("ssm", "hybrid") \
-        and shape.kind != "decode" and shape.seq_len % ms.model == 0
+        and shape.kind == "train" and shape.seq_len % ms.model == 0
     from repro.core import sharding as SHmod
     from repro.models import moe as moe_mod
     if moe_ep and arch.moe is not None:
@@ -209,18 +212,36 @@ def build_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
                               moe_ep=(moe_ep and arch.moe is not None))
     plan = sched.plan(arch, shape, ms)
 
-    pspecs = plan.param_specs()
-    params_sds = _sds(C.abstract_params(arch), pspecs, mesh)
     B, S = shape.global_batch, shape.seq_len
     # FS and uniform-DP shard the batch over every mesh axis
     full_batch = plan.uniform in ("FS", "DP") and shape.kind == "train"
-    tok_ns = NamedSharding(mesh, SH.token_spec(ms, B, full=full_batch))
     # layer-boundary activation sharding constraint (seq-sharding is
     # meaningless under FS/uniform-DP where `model` already carries batch)
     seq_ok = seq_ok and not full_batch
+    meta = {"arch": arch_name, "shape": shape_name,
+            "mesh": "2x16x16" if multi_pod else "16x16",
+            "opt_preset": opt_preset, "microbatches": plan.microbatches,
+            "seq_shard": seq_ok,
+            "method": plan.plan.method, "feasible": plan.plan.feasible,
+            "predicted": plan.plan.cost,
+            "assignment": {k: str(v) for k, v in plan.assignment.items()}}
+
+    if shape.kind != "train":
+        # the engine's paged step at the shape's batch and length: one
+        # prompt chunk (B = 1) for prefill, one token a slot for decode
+        kind = "paged_prefill" if shape.kind == "prefill" else "paged_decode"
+        geom = IR.ServeGeom(slots=B, max_len=S, block_size=SERVE_BLOCK,
+                            prefill_chunk=SERVE_CHUNK)
+        fn = ST.jit_step(kind, IR.build_step_fn(arch, kind))
+        args = IR.step_arguments(arch, kind, geom, mesh=mesh, plan=plan)
+        meta["serve_geom"] = dataclasses.asdict(geom)
+        return fn, args, plan, meta, mesh
+
+    pspecs = plan.param_specs()
+    params_sds = _sds(C.abstract_params(arch), pspecs, mesh)
+    tok_ns = NamedSharding(mesh, SH.token_spec(ms, B, full=full_batch))
     act_ns = NamedSharding(mesh, P(SH.batch_axes(ms, B, full=full_batch),
                                    "model" if seq_ok else None, None))
-
     fe_sds = None
     if arch.frontend == "vision":
         fe_sds = jax.ShapeDtypeStruct((B, arch.n_img_tokens, arch.d_model),
@@ -231,70 +252,43 @@ def build_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
                                       jnp.bfloat16, sharding=tok_ns.update(
                                           spec=P(tok_ns.spec[0], None, None)))
 
-    meta = {"arch": arch_name, "shape": shape_name,
-            "mesh": "2x16x16" if multi_pod else "16x16",
-            "opt_preset": opt_preset, "microbatches": plan.microbatches,
-            "seq_shard": seq_ok,
-            "method": plan.plan.method, "feasible": plan.plan.feasible,
-            "predicted": plan.plan.cost,
-            "assignment": {k: str(v) for k, v in plan.assignment.items()}}
-
-    if shape.kind == "train":
-        # "full" per-layer remat inside the layer scan: O(1) activation
-        # memory in depth — the production default for these model sizes
-        # ("selective" saves every dot output; see EXPERIMENTS.md §Perf)
-        remat_policy = remat or "full"
-        if plan.uniform == "FS" and remat is None:
-            # FS: per-device batch is 1 — activations are tiny, so skip
-            # grad accumulation (halves ZeRO gathers + grad reductions).
-            # Keep full remat: under "selective" XLA holds every layer's
-            # *gathered* weights for backward (53 GB/dev temps, §Perf it.3)
-            plan.microbatches = 1
-        opt_init, _ = optimizer = O.adamw(
-            1e-4, quantized=(opt_preset == "adamw8bit"))
-        opt_sds_raw = jax.eval_shape(opt_init, C.abstract_params(arch))
-        opt_specs = SH.opt_state_specs(opt_sds_raw, pspecs, ms)
-        opt_sds = _sds(opt_sds_raw, opt_specs, mesh)
-        grad_ns = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
-        step = ST.make_train_step(arch, optimizer,
-                                  microbatches=plan.microbatches,
-                                  remat=remat_policy, act_sharding=act_ns,
-                                  grad_shardings=grad_ns)
-        tok = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=tok_ns)
-        batch = {"tokens": tok, "labels": tok}
-        if fe_sds is not None:
-            batch["frontend"] = fe_sds
-        out_shardings = (jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs),
-                         jax.tree.map(lambda s: NamedSharding(mesh, s),
-                                      opt_specs),
-                         None)
-        fn = ST.jit_step("train", step, out_shardings=out_shardings)
-        args = (params_sds, opt_sds, batch)
-        meta["remat"] = remat_policy
-    else:
-        cache_sds_raw = jax.eval_shape(
-            functools.partial(T.init_cache, arch, B, S, jnp.bfloat16))
-        cspecs = plan.cache_specs(B)
-        cache_sds = _sds(cache_sds_raw, cspecs, mesh)
-        if shape.kind == "prefill":
-            pstep = ST.make_prefill_step(arch, act_sharding=act_ns)
-            tok = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=tok_ns)
-            if fe_sds is not None:
-                fn = ST.jit_step("prefill", lambda p, c, t, f: pstep(p, c, t, f))
-                args = (params_sds, cache_sds, tok, fe_sds)
-            else:
-                fn = ST.jit_step("prefill", lambda p, c, t: pstep(p, c, t))
-                args = (params_sds, cache_sds, tok)
-        else:  # decode
-            dstep = ST.make_decode_step(arch, act_sharding=act_ns)
-            tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_ns)
-            fn = ST.jit_step("decode", dstep)
-            args = (params_sds, cache_sds, tok)
+    # "full" per-layer remat inside the layer scan: O(1) activation
+    # memory in depth — the production default for these model sizes
+    # ("selective" saves every dot output; see EXPERIMENTS.md §Perf)
+    remat_policy = remat or "full"
+    if plan.uniform == "FS" and remat is None:
+        # FS: per-device batch is 1 — activations are tiny, so skip
+        # grad accumulation (halves ZeRO gathers + grad reductions).
+        # Keep full remat: under "selective" XLA holds every layer's
+        # *gathered* weights for backward (53 GB/dev temps, §Perf it.3)
+        plan.microbatches = 1
+    opt_init, _ = optimizer = O.adamw(
+        1e-4, quantized=(opt_preset == "adamw8bit"))
+    opt_sds_raw = jax.eval_shape(opt_init, C.abstract_params(arch))
+    opt_specs = SH.opt_state_specs(opt_sds_raw, pspecs, ms)
+    opt_sds = _sds(opt_sds_raw, opt_specs, mesh)
+    grad_ns = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
+    step = ST.make_train_step(arch, optimizer,
+                              microbatches=plan.microbatches,
+                              remat=remat_policy, act_sharding=act_ns,
+                              grad_shardings=grad_ns)
+    tok = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=tok_ns)
+    batch = {"tokens": tok, "labels": tok}
+    if fe_sds is not None:
+        batch["frontend"] = fe_sds
+    out_shardings = (jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs),
+                     jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                  opt_specs),
+                     None)
+    fn = ST.jit_step("train", step, out_shardings=out_shardings)
+    args = (params_sds, opt_sds, batch)
+    meta["remat"] = remat_policy
     return fn, args, plan, meta, mesh
 
 
 def model_flops(arch_name: str, shape_name: str) -> float:
-    """6·N·D (train) / 2·N·D (inference), N = active params."""
+    """6·N·D (train) / 2·N·D (inference), N = active params, D the tokens
+    the lowered step processes."""
     arch = get_arch(arch_name)
     shape = SHAPES[shape_name]
     n_active = C.active_param_count(arch)
@@ -302,9 +296,8 @@ def model_flops(arch_name: str, shape_name: str) -> float:
         tokens = shape.global_batch * shape.seq_len
         return 6.0 * n_active * tokens
     if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_active * tokens
-    return 2.0 * n_active * shape.global_batch       # decode: 1 token/seq
+        return 2.0 * n_active * SERVE_CHUNK          # one prompt chunk
+    return 2.0 * n_active * shape.global_batch       # decode: 1 token/slot
 
 
 def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,

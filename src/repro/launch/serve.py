@@ -1,24 +1,19 @@
 """Serving launcher CLI.
 
     PYTHONPATH=src python -m repro.launch.serve --arch zamba2-2.7b --smoke \
-        --engine continuous --requests 8 --prompt-len 16 --max-new 12
+        --requests 8 --prompt-len 16 --max-new 12
 
     # seeded nucleus sampling with stop tokens
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \
         --temperature 0.8 --top-p 0.95 --seed 7 --stop 11,12
 
---engine continuous  (default) continuous batching over the unified serving
-                     cache (paged KV / latent block pools + slot-state pools
-                     for SSM, cross-attn and encoder K/V state) with chunked
-                     prefill and per-slot positions (repro/serving/); emits
-                     a JSON metrics report (TTFT/TPOT/occupancy/tokens-per-
-                     sec).  Serves every config in the zoo — zamba2's
-                     weight-shared block, whisper's encoder-decoder and
-                     deepseek's MLA included.
---engine wave        DEPRECATED: the wave decode path was deleted; this now
-                     exercises the runtime.server.Server compatibility shim,
-                     which delegates every token to the continuous engine
-                     (greedy-only — the legacy API has no sampling field).
+Serves through the continuous-batching engine over the unified serving
+cache (paged KV / latent block pools + slot-state pools for SSM, cross-attn
+and encoder K/V state) with chunked prefill and per-slot positions
+(repro/serving/), and prints a metrics line (TTFT/TPOT/occupancy/tokens-
+per-sec).  Serves every config in the zoo — zamba2's weight-shared block,
+whisper's encoder-decoder and deepseek's MLA included.
+
 --temperature /
 --top-k / --top-p    per-request SamplingParams for every submitted request
                      (temperature 0 = exact greedy argmax, the default).
@@ -29,13 +24,13 @@
                      request with finish_reason="stop" (the stop token is
                      the last entry of token_ids).
 --logprobs           attach per-token logprobs to each RequestOutput.
---share-prefix       cross-request prefix caching (continuous engine, purely
-                     paged archs only): prompts share a system prefix of
+--share-prefix       cross-request prefix caching (purely paged archs only):
+                     prompts share a system prefix of
                      --shared-prefix-len tokens, later requests reuse its
                      cached blocks and start prefill at the matched boundary;
                      the report line gains the prefix-cache hit rate.
 
-Observability (continuous engine only):
+Observability:
 --trace-out PATH     record a Chrome trace-event JSON of the whole run —
                      one track per engine phase (admission / prefix-match /
                      prefill launch and sync / decode launch / sample
@@ -71,15 +66,13 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--engine", choices=("wave", "continuous"),
-                    default="continuous")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--block-size", type=int, default=16,
-                    help="paged-KV block size (continuous engine)")
+                    help="paged-KV block size")
     ap.add_argument("--prefill-chunk", type=int, default=64,
                     help="prompt tokens prefilled per engine step")
     ap.add_argument("--num-blocks", type=int, default=None,
@@ -98,14 +91,14 @@ def main():
     ap.add_argument("--logprobs", action="store_true",
                     help="attach per-token logprobs to each RequestOutput")
     ap.add_argument("--share-prefix", action="store_true",
-                    help="continuous engine only: reuse cached KV blocks "
-                         "across requests sharing a prompt prefix")
+                    help="reuse cached KV blocks across requests sharing a "
+                         "prompt prefix")
     ap.add_argument("--shared-prefix-len", type=int, default=None,
                     help="with --share-prefix: length of the common system "
                          "prefix prepended to every prompt (default: "
                          "prompt-len, i.e. suffixes of 4 unique tokens)")
     ap.add_argument("--metrics-out", default=None,
-                    help="write the continuous engine's JSON metrics here")
+                    help="write the engine's JSON metrics here")
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome trace-event JSON (Perfetto) of the "
                          "run: per-phase tracks + per-request spans")
@@ -120,7 +113,7 @@ def main():
                     help="sliding-window seconds for the workload signal "
                          "vector (default 10)")
     ap.add_argument("--sanitize", action="store_true",
-                    help="continuous engine only: attach the paged-cache "
+                    help="attach the paged-cache "
                          "sanitizer (analysis/sanitizer.py) — records "
                          "allocation sites, cross-validates refcounts "
                          "against block tables and the prefix index every "
@@ -149,37 +142,6 @@ def main():
     else:
         prompts = [rng.integers(1, arch.vocab, size=args.prompt_len)
                    .astype(np.int32) for _ in range(args.requests)]
-
-    if args.engine == "wave":
-        if (args.temperature != 0.0 or args.top_k != 0 or args.top_p != 1.0
-                or args.seed != 0 or args.stop or args.logprobs):
-            ap.error("--engine wave is greedy-only (the legacy API has no "
-                     "sampling field): drop the sampling flags or use "
-                     "--engine continuous")
-        if args.trace_out or args.prom_out or args.metrics_every is not None:
-            ap.error("--trace-out/--prom-out/--metrics-every need the "
-                     "continuous engine (the wave shim exposes no "
-                     "telemetry): use --engine continuous")
-        if args.sanitize:
-            ap.error("--sanitize needs the continuous engine (the wave "
-                     "shim exposes no cache hooks): use --engine "
-                     "continuous")
-        from repro.runtime.server import Request, Server
-        server = Server(arch, params, mesh, slots=args.slots,
-                        max_len=args.max_len,
-                        block_size=args.block_size,
-                        num_blocks=args.num_blocks,
-                        prefill_chunk=args.prefill_chunk)
-        for i, p in enumerate(prompts):
-            server.submit(Request(id=i, prompt=p,
-                                  max_new_tokens=args.max_new))
-        wall = server.run_until_drained()
-        total = sum(len(r.out_tokens) for r in server.completed)
-        print(f"[wave-shim] {len(server.completed)} requests, {total} "
-              f"tokens, {wall:.2f}s wall ({total / max(wall, 1e-9):.1f} "
-              f"tok/s host-wall), {server.decode_steps} decode steps "
-              f"(continuous engine under the hood)")
-        return
 
     from repro.serving import (ChromeTracer, ContinuousBatchingEngine,
                                Request, SamplingParams, ServingMetrics,

@@ -144,34 +144,6 @@ def init_shared(key, arch: ArchConfig, dtype) -> Params:
 # caches
 # ---------------------------------------------------------------------------
 
-def init_block_cache(kind: str, arch: ArchConfig, batch: int, max_len: int,
-                     dtype=jnp.bfloat16) -> Optional[Params]:
-    if kind in ("attn", "moe_attn"):
-        return L.init_attention_cache(attn_cfg_for(arch), batch, max_len, dtype)
-    if kind in ("mla", "mla_dense"):
-        return MLA.init_mla_cache(mla_cfg_for(arch), batch, max_len, dtype)
-    if kind == "mamba2":
-        return M2.init_mamba2_cache(ssm_cfg_for(arch), batch)
-    if kind == "cross_attn":
-        cfg = attn_cfg_for(arch, causal=False, use_rope=False)
-        shp = (batch, arch.n_img_tokens, cfg.n_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}
-    if kind == "wdec":
-        cfg = attn_cfg_for(arch, causal=False, use_rope=False)
-        enc_len = arch.encoder.seq_len if arch.encoder else 1500
-        shp = (batch, enc_len, cfg.n_kv_heads, cfg.head_dim)
-        self_cache = L.init_attention_cache(
-            attn_cfg_for(arch, use_rope=False), batch, max_len, dtype)
-        return {"self": self_cache,
-                "cross": {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}}
-    if kind == "shared_attn":
-        cfg = attn_cfg_for(arch, d_model=2 * arch.d_model, n_heads=arch.n_heads)
-        return L.init_attention_cache(cfg, batch, max_len, dtype)
-    if kind == "enc_attn":
-        return None
-    raise ValueError(kind)
-
-
 def init_paged_block_cache(kind: str, arch: ArchConfig, num_blocks: int,
                            block_size: int, dtype=jnp.bfloat16, *,
                            slots: int = 0) -> Params:
@@ -204,7 +176,6 @@ def init_paged_block_cache(kind: str, arch: ArchConfig, num_blocks: int,
                 f"slot-state pool for {kind!r} needs slots > 0 (one state "
                 f"row per engine slot + the null row)")
         if kind == "mamba2":
-            # fp32 recurrent state, matching init_block_cache's wave path
             return M2.init_mamba2_cache(ssm_cfg_for(arch), slots + 1)
         if kind == "wdec":
             if arch.encoder is None:
@@ -231,20 +202,6 @@ def init_paged_block_cache(kind: str, arch: ArchConfig, num_blocks: int,
 # apply
 # ---------------------------------------------------------------------------
 
-def _layer_of(cache, layer):
-    return jax.tree.map(
-        lambda t: jax.lax.dynamic_index_in_dim(t, layer, keepdims=False),
-        cache)
-
-
-def _put_layer(stacked, layer, old, new):
-    """Write ``new`` back at ``layer`` of ``stacked``; a leaf returned as it
-    was read (a read-only cache) is not written."""
-    return jax.tree.map(
-        lambda s, o, n: s if n is o else jax.lax.dynamic_update_index_in_dim(
-            s, n.astype(s.dtype), layer, 0), stacked, old, new)
-
-
 def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 x0: Optional[Array] = None,
                 cross_input: Optional[Array] = None,
@@ -256,22 +213,16 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 slot_ids: Optional[Array] = None,
                 layer: Optional[Array] = None,
                 impl: str = "xla"):
-    """-> (x, new_cache, aux_loss).  ``block_tables`` selects the paged-KV
-    decode path for attn-family kinds; ``slot_ids`` selects the slot-state
-    pool path for mamba2 / cross_attn (see serving/cache_manager.py).
+    """-> (x, new_cache, aux_loss).  ``cache`` is the block's serving pool
+    (transformer.init_paged_cache), or None for training and the encoder.
+    ``block_tables`` selects the paged-KV path for attn-family kinds;
+    ``slot_ids`` selects the slot-state pool path for mamba2 / cross_attn
+    (see serving/cache_manager.py).
 
-    With ``layer`` (an int32 scalar) ``cache`` is the block's cache stacked
+    With ``layer`` (an int32 scalar) ``cache`` is the block's pool stacked
     over the segment's layers — the scan carry of
-    transformer._apply_segment — and the stacked cache is returned: serving
-    pools are read and written in place at (layer, row); contiguous (wave)
-    caches run on this layer's slice, written back at ``layer``."""
-    if layer is not None and block_tables is None and slot_ids is None:
-        c = _layer_of(cache, layer)
-        x, nc, aux = apply_block(p, kind, arch, x, x0=x0,
-                                 cross_input=cross_input, shared=shared,
-                                 cache=c, positions=positions,
-                                 new_lens=new_lens, impl=impl)
-        return x, _put_layer(cache, layer, c, nc), aux
+    transformer._apply_segment — read and written in place at (layer, row),
+    and the stacked pool is returned."""
     aux = ZERO
     if (block_tables is not None or slot_ids is not None) and \
             kind not in ("attn", "moe_attn", "mamba2", "cross_attn",
@@ -301,9 +252,10 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                 positions=positions, block_tables=block_tables,
                 new_lens=new_lens, layer=layer)
         else:
-            h, new_cache = MLA.mla_attention(p["attn"], mla_cfg_for(arch),
-                                             norm_apply(arch, p["norm1"], x),
-                                             cache=cache, positions=positions)
+            h = MLA.mla_attention(p["attn"], mla_cfg_for(arch),
+                                  norm_apply(arch, p["norm1"], x),
+                                  positions=positions)
+            new_cache = None
         x = x + h
         if kind == "mla":
             h, aux = MOE.moe(p["moe"], moe_cfg_for(arch),
@@ -322,7 +274,7 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
                                           impl=impl)
         else:
             h, new_cache = M2.mamba2(p["mixer"], ssm_cfg_for(arch), normed,
-                                     cache=cache, impl=impl)
+                                     impl=impl)
         return x + h, new_cache, aux
 
     if kind == "cross_attn":
@@ -339,8 +291,7 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
         else:
             h, new_cache = L.attention(p["attn"], cfg,
                                        norm_apply(arch, p["norm1"], x),
-                                       kv_input=cross_input, cache=cache,
-                                       impl=impl)
+                                       kv_input=cross_input, impl=impl)
         x = x + h
         h = L.mlp(p["mlp"], norm_apply(arch, p["norm2"], x), arch.act)
         x = x + jnp.tanh(p["mlp_gate"].astype(h.dtype)) * h
@@ -349,11 +300,10 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
     if kind == "wdec":
         self_cfg = attn_cfg_for(arch, causal=True, use_rope=False)
         cross_cfg = attn_cfg_for(arch, causal=False, use_rope=False)
-        c_self = cache["self"] if cache is not None else None
-        c_cross = cache["cross"] if cache is not None else None
         h, nc_self = L.attention(p["attn"], self_cfg,
                                  norm_apply(arch, p["norm1"], x),
-                                 cache=c_self, positions=positions,
+                                 cache=None if cache is None else cache["self"],
+                                 positions=positions,
                                  block_tables=block_tables,
                                  new_lens=new_lens, layer=layer, impl=impl)
         x = x + h
@@ -362,20 +312,18 @@ def apply_block(p: Params, kind: str, arch: ArchConfig, x: Array, *,
             # read-only here (written once at admission —
             # transformer.admit_slot runs the encoder)
             at = L.layer_index(layer, slot_ids)
-            rows = {"k": c_cross["k"][at], "v": c_cross["v"][at]}
+            rows = {"k": cache["cross"]["k"][at], "v": cache["cross"]["v"][at]}
             h, _ = L.attention(p["xattn"], cross_cfg,
                                norm_apply(arch, p["norm2"], x),
                                cache=rows, impl=impl)
-            nc_cross = c_cross
         else:
-            h, nc_cross = L.attention(p["xattn"], cross_cfg,
-                                      norm_apply(arch, p["norm2"], x),
-                                      kv_input=cross_input, cache=c_cross,
-                                      impl=impl)
+            h, _ = L.attention(p["xattn"], cross_cfg,
+                               norm_apply(arch, p["norm2"], x),
+                               kv_input=cross_input, impl=impl)
         x = x + h
         h = L.mlp(p["mlp"], norm_apply(arch, p["norm3"], x), arch.act)
-        new_cache = ({"self": nc_self, "cross": nc_cross}
-                     if cache is not None else None)
+        new_cache = (None if cache is None
+                     else {"self": nc_self, "cross": cache["cross"]})
         return x + h, new_cache, aux
 
     if kind == "shared_attn":

@@ -137,93 +137,72 @@ def init_attention(key, cfg: AttnConfig, dtype=jnp.float32) -> Params:
     return p
 
 
-def _expand_kv(t: Array, n_heads: int) -> Array:
-    """(B,T,Hkv,D) -> (B,T,H,D) by broadcasting each kv head over its q-group.
-
-    Used by the contiguous-cache :func:`_sdpa` only; the paged path
-    (:func:`_paged_sdpa`) contracts each kv head with its own q-group and
-    never materialises the copy.  Broadcast-then-reshape keeps the head axis
-    shardable over `model` to the same degree as q's head axis (the kv
-    source is replicated when Hkv < mesh model size — see core/sharding.py)."""
-    B, T, Hkv, D = t.shape
-    group = n_heads // Hkv
-    t = jnp.broadcast_to(t[:, :, :, None, :], (B, T, Hkv, group, D))
-    return t.reshape(B, T, n_heads, D)
-
-
 SDPA_CHUNK = 512          # q-block size for the chunked XLA path
 SDPA_CHUNK_THRESHOLD = 1024   # chunk when S*T exceeds threshold^2
 
 
-def _sdpa_dense(q, k, v, *, causal, scale, q_pos, kv_len):
-    B, S, H, D = q.shape
-    T = k.shape[1]
-    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
-    mask = None
+def _sdpa_dense(q, k, v, *, causal, scale):
+    """q (B,S,Hkv,G,D) against k/v (B,T,Hkv,D) -> (B,S,Hkv,G,D)."""
+    S, T = q.shape[1], k.shape[1]
+    logits = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32) * scale
     if causal:
-        qp = q_pos if q_pos is not None else jnp.arange(S)
-        kp = jnp.arange(T)
-        mask = qp[:, None] >= kp[None, :]          # (S, T)
-    if kv_len is not None:
-        valid = jnp.arange(T) < kv_len             # (T,)
-        mask = valid[None, :] if mask is None else (mask & valid[None, :])
-    if mask is not None:
-        logits = jnp.where(mask[None, None], logits, -1e30)
+        mask = jnp.arange(S)[:, None] >= jnp.arange(T)[None, :]   # (S, T)
+        logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhst,bthd->bshd", probs, v)
+    return jnp.einsum("bkgst,btkd->bskgd", probs, v)
 
 
-def _sdpa_chunked(q, k, v, *, causal, scale, q_pos, kv_len,
-                  chunk=SDPA_CHUNK):
+def _sdpa_chunked(q, k, v, *, causal, scale, chunk=SDPA_CHUNK):
     """Scan over query blocks: peak logits memory B*H*chunk*T instead of
     B*H*S*T.  XLA lowers the scan body once; this is the memory-sane lowering
     the dry-run uses for 4k-32k sequences (the Pallas kernel replaces it on
-    real TPUs)."""
-    B, S, H, D = q.shape
+    real TPUs).  Shapes as :func:`_sdpa_dense`."""
+    B, S, Hkv, G, D = q.shape
     T = k.shape[1]
     C = min(chunk, S)
     pad = (-S) % C
-    qp = q_pos if q_pos is not None else jnp.arange(S)
+    qp = jnp.arange(S)
     if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
         qp = jnp.pad(qp, (0, pad), constant_values=-1)   # -1 => fully masked
     nq = q.shape[1] // C
-    q_blocks = jnp.moveaxis(q.reshape(B, nq, C, H, D), 1, 0)
+    q_blocks = jnp.moveaxis(q.reshape(B, nq, C, Hkv, G, D), 1, 0)
     p_blocks = qp.reshape(nq, C)
     kp = jnp.arange(T)
 
     def body(_, xs):
-        qb, pb = xs                                      # (B,C,H,D), (C,)
-        lg = jnp.einsum("bchd,bthd->bhct", qb, k).astype(jnp.float32) * scale
+        qb, pb = xs                                  # (B,C,Hkv,G,D), (C,)
+        lg = jnp.einsum("bckgd,btkd->bkgct", qb, k).astype(jnp.float32) * scale
         if causal:
             mask = pb[:, None] >= kp[None, :]
         else:
             mask = (pb[:, None] >= 0) & jnp.ones((1, T), bool)
-        if kv_len is not None:
-            mask = mask & (kp[None, :] < kv_len)
-        lg = jnp.where(mask[None, None], lg, -1e30)
+        lg = jnp.where(mask, lg, -1e30)
         pr = jax.nn.softmax(lg, axis=-1).astype(v.dtype)
-        ob = jnp.einsum("bhct,bthd->bchd", pr, v)
+        ob = jnp.einsum("bkgct,btkd->bckgd", pr, v)
         return 0.0, ob
 
     _, out = jax.lax.scan(body, 0.0, (q_blocks, p_blocks))
-    out = jnp.moveaxis(out, 0, 1).reshape(B, nq * C, H, D)
+    out = jnp.moveaxis(out, 0, 1).reshape(B, nq * C, Hkv, G, D)
     return out[:, :S]
 
 
-def _sdpa(q: Array, k: Array, v: Array, *, causal: bool, scale: float,
-          q_pos: Optional[Array] = None, kv_len: Optional[Array] = None) -> Array:
+def _sdpa(q: Array, k: Array, v: Array, *, causal: bool,
+          scale: float) -> Array:
     """q: (B,S,H,D); k,v: (B,T,Hkv,D) with Hkv | H.  Pure-jnp reference path
     (the 'xla' impl); auto-switches to the q-block-chunked form when the
-    logits tensor would be large.  ``kv_len`` masks slots >= kv_len."""
+    logits tensor would be large.  As in :func:`_paged_sdpa`, each kv head
+    is contracted with its own group of G = H/Hkv query heads, so K/V are
+    never copied to H heads."""
     B, S, H, D = q.shape
-    T = k.shape[1]
-    k, v = _expand_kv(k.astype(q.dtype), H), _expand_kv(v.astype(q.dtype), H)
+    T, Hkv = k.shape[1], k.shape[2]
+    q = q.reshape(B, S, Hkv, H // Hkv, D)      # kv head k meets q group k
+    k, v = k.astype(q.dtype), v.astype(q.dtype)
     if S * T > SDPA_CHUNK_THRESHOLD ** 2 and S > SDPA_CHUNK:
-        return _sdpa_chunked(q, k, v, causal=causal, scale=scale,
-                             q_pos=q_pos, kv_len=kv_len)
-    return _sdpa_dense(q, k, v, causal=causal, scale=scale,
-                       q_pos=q_pos, kv_len=kv_len)
+        out = _sdpa_chunked(q, k, v, causal=causal, scale=scale)
+    else:
+        out = _sdpa_dense(q, k, v, causal=causal, scale=scale)
+    return out.reshape(B, S, H, D)
 
 
 def _paged_sdpa(q: Array, k: Array, v: Array, *, scale: float,
@@ -235,10 +214,10 @@ def _paged_sdpa(q: Array, k: Array, v: Array, *, scale: float,
     (G = 1 is MHA), so the gathered K/V is read once at Hkv heads and never
     copied to H; the products and their sums over D are those of the
     broadcast form.  Masked entries contribute exactly-zero probability (exp
-    underflows), so the result equals the contiguous-cache decode path on
-    the unmasked prefix (on CPU: bitwise in bf16, to float rounding in
-    float32).  The greedy-parity test in tests/test_serving.py relies on
-    this."""
+    underflows), so the rows of the table past a sequence's length and the
+    padded rows of a chunk change nothing: the result is attention over the
+    sequence's own prefix.  The greedy-parity test in tests/test_serving.py
+    relies on this."""
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     q = q.reshape(B, S, Hkv, H // Hkv, D)      # kv head k meets q group k
@@ -371,12 +350,13 @@ def attention(p: Params, cfg: AttnConfig, x: Array, *,
               impl: str = "xla") -> tuple[Array, Optional[Params]]:
     """Self- or cross-attention.
 
-    cache (decode): {"k": (B,T,Hkv,D), "v": ..., "pos": scalar int32} — new
-    k/v written at ``pos``; returns updated cache.  For cross-attention the
-    cache holds precomputed encoder K/V and is not updated.  When
-    ``block_tables`` is given the cache is a paged block pool instead and
+    With ``block_tables`` the cache is a paged block pool and the call
     dispatches to :func:`paged_attention` (per-sequence positions; with
-    ``layer``, layer ``layer`` of the stacked pools).
+    ``layer``, layer ``layer`` of the stacked pools), which returns the
+    updated pool.  Otherwise a ``cache`` holds read-only cross K/V rows
+    {"k": (B,T,Hkv,D), "v": ...} (the slot-state pools' rows), ``kv_input``
+    is the source of cross K/V, and with neither this is training or
+    encoder self-attention; these return no cache.
     """
     if block_tables is not None:
         assert cache is not None and positions is not None
@@ -384,70 +364,43 @@ def attention(p: Params, cfg: AttnConfig, x: Array, *,
                                block_tables=block_tables, new_lens=new_lens,
                                layer=layer)
     B, S, _ = x.shape
-    src = kv_input if kv_input is not None else x
     q = dense(p["wq"], x).reshape(B, S, cfg.n_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = rmsnorm(p["q_norm"], q)
     scale = cfg.softmax_scale or (1.0 / math.sqrt(cfg.head_dim))
 
-    is_cross = kv_input is not None or (cache is not None and "pos" not in cache)
-    if is_cross:
-        if kv_input is not None:      # compute (and possibly store) cross K/V
+    if kv_input is not None or cache is not None:       # cross-attention
+        if kv_input is not None:
             T = kv_input.shape[1]
             k = dense(p["wk"], kv_input).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
             v = dense(p["wv"], kv_input).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
             if cfg.qk_norm:
                 k = rmsnorm(p["k_norm"], k)
-            new_cache = ({"k": k.astype(cache["k"].dtype),
-                          "v": v.astype(cache["v"].dtype)}
-                         if cache is not None else None)
-        else:                          # precomputed cross K/V from the cache
+        else:
             k, v = cache["k"], cache["v"]
-            new_cache = cache
         if cfg.use_rope:
             q = apply_rope(q, positions if positions is not None else jnp.arange(S),
                            cfg.rope_theta)
-        out = _sdpa(q, k.astype(q.dtype), v.astype(q.dtype), causal=False, scale=scale)
+        out = _sdpa(q, k, v, causal=False, scale=scale)
     else:
-        k = dense(p["wk"], src).reshape(B, src.shape[1], cfg.n_kv_heads, cfg.head_dim)
-        v = dense(p["wv"], src).reshape(B, src.shape[1], cfg.n_kv_heads, cfg.head_dim)
+        k = dense(p["wk"], x).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        v = dense(p["wv"], x).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
         if cfg.qk_norm:
             k = rmsnorm(p["k_norm"], k)
-        if cache is not None and "pos" in cache:
-            # decode: write k/v at cache["pos"], attend to the full prefix
-            pos = cache["pos"]
-            if cfg.use_rope:
-                pp = jnp.full((S,), 0, jnp.int32) + pos + jnp.arange(S)
-                q = apply_rope(q, pp, cfg.rope_theta)
-                k = apply_rope(k, pp, cfg.rope_theta)
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
-            out = _sdpa(q, ck, cv, causal=True, scale=scale,
-                        q_pos=pos + jnp.arange(S), kv_len=pos + S)
-            new_cache = {"k": ck, "v": cv, "pos": pos + S}
+        if positions is None:
+            positions = jnp.arange(S)
+        if cfg.use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        if impl == "pallas" and cfg.causal:
+            from repro.kernels import ops as kops
+            out = kops.flash_attention(q, k, v, scale=scale)
         else:
-            if positions is None:
-                positions = jnp.arange(S)
-            if cfg.use_rope:
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
-            if impl == "pallas" and cfg.causal and kv_input is None:
-                from repro.kernels import ops as kops
-                out = kops.flash_attention(q, k, v, scale=scale)
-            else:
-                out = _sdpa(q, k, v, causal=cfg.causal, scale=scale)
-            new_cache = None
+            out = _sdpa(q, k, v, causal=cfg.causal, scale=scale)
     y = dense(p["wo"], out.reshape(B, S, cfg.q_dim))
     if cfg.gated:
         y = jnp.tanh(p["gate"].astype(y.dtype)) * y
-    return y, new_cache
-
-
-def init_attention_cache(cfg: AttnConfig, batch: int, max_len: int,
-                         dtype=jnp.bfloat16) -> Params:
-    shp = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype),
-            "pos": jnp.zeros((), jnp.int32)}
+    return y, None
 
 
 def init_paged_attention_cache(cfg: AttnConfig, num_blocks: int,

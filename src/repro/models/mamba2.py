@@ -291,7 +291,7 @@ def mamba2_slot(p: Params, cfg: Mamba2Config, x: Array, *,
     ``layer`` the pool is the stacked (L, slots+1, ...) scan carry and the
     rows are those of layer ``layer`` (layers.layer_index).
 
-    Gather rows -> run the exact wave-path recurrence/chunked scan on them
+    Gather rows -> run :func:`mamba2`'s recurrence/chunked scan on them
     (decode when S==1 and new_lens is None, chunk-prefill otherwise, with
     the SSM state carried as h0 across chunks) -> scatter updated rows back.
     """

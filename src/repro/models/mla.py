@@ -53,13 +53,6 @@ def init_mla(key, cfg: MLAConfig, dtype=jnp.float32) -> Params:
     }
 
 
-def init_mla_cache(cfg: MLAConfig, batch: int, max_len: int,
-                   dtype=jnp.bfloat16) -> Params:
-    return {"c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype),
-            "k_rope": jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype),
-            "pos": jnp.zeros((), jnp.int32)}
-
-
 def init_paged_mla_cache(cfg: MLAConfig, num_blocks: int, block_size: int,
                          dtype=jnp.bfloat16) -> Params:
     """Physical latent block pools shared by all requests (no batch axis;
@@ -89,8 +82,8 @@ def _attend(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, p, *,
     """Latent-space attention: score via up-projected keys, value from c_kv.
 
     q_nope: (B,S,H,dn)  q_rope: (B,S,H,dr)  c_kv: (B,T,r)  k_rope: (B,T,dr)
-    q_positions: (S,) shared across the batch (contiguous cache) or (B,S)
-    per-row (paged serving); kv_len: None, scalar, or (B,) per-row.
+    q_positions: (S,) shared across the batch (no cache) or (B,S) per-row
+    (paged serving); kv_len: None or (B,) per-row.
     Absorbed form: score_nope = (q_nope @ wk_b^T) @ c_kv^T — contracts in the
     rank-r latent space, so no per-token key materialization (decode-fast).
     Long sequences scan over q blocks (logits memory B*H*C*T, not B*H*S*T).
@@ -105,15 +98,14 @@ def _attend(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, p, *,
     krope = k_rope.astype(q_rope.dtype)
     qpb = jnp.broadcast_to(q_positions, (B, S)) \
         if q_positions.ndim == 1 else q_positions             # (B, S)
-    kvl = None if kv_len is None else jnp.broadcast_to(kv_len, (B,))
 
     def block(q_lat_b, q_rope_b, pos_b):
         s_nope = jnp.einsum("bshr,btr->bhst", q_lat_b, ckv)
         s_rope = jnp.einsum("bshd,btd->bhst", q_rope_b, krope)
         lg = (s_nope + s_rope).astype(jnp.float32) * scale
         mask = pos_b[:, :, None] >= kp[None, None, :]         # (B, C, T)
-        if kvl is not None:
-            mask = mask & (kp[None, None, :] < kvl[:, None, None])
+        if kv_len is not None:
+            mask = mask & (kp[None, None, :] < kv_len[:, None, None])
         lg = jnp.where(mask[:, None], lg, -1e30)
         pr = jax.nn.softmax(lg, axis=-1).astype(ckv.dtype)
         return jnp.einsum("bhst,btr->bshr", pr, ckv)      # latent context
@@ -152,8 +144,8 @@ def mla_paged_attention(p: Params, cfg: MLAConfig, x: Array, *,
     gathered logical view with per-sequence causal/length masks.  With
     ``layer`` the pools are the stacked scan carry, written and gathered in
     place at that layer (layers.paged_write_gather).  Masked entries
-    contribute exactly-zero probability, so greedy decode is token-identical
-    to the contiguous-cache path on the unmasked prefix."""
+    contribute exactly-zero probability, so each row attends over its own
+    prefix only."""
     B, S, _ = x.shape
     BS = cache["c_kv"].shape[-2]
     kv = L.dense(p["wkv_a"], x)
@@ -177,33 +169,18 @@ def mla_paged_attention(p: Params, cfg: MLAConfig, x: Array, *,
 
 
 def mla_attention(p: Params, cfg: MLAConfig, x: Array, *,
-                  cache: Optional[Params] = None,
-                  positions: Optional[Array] = None) -> tuple[Array, Optional[Params]]:
-    B, S, _ = x.shape
+                  positions: Optional[Array] = None) -> Array:
+    """Latent attention over the call's own tokens (training, and the
+    forward without a cache); serving goes through
+    :func:`mla_paged_attention`."""
+    S = x.shape[1]
     kv = L.dense(p["wkv_a"], x)
     c_kv = L.rmsnorm(p["kv_norm"], kv[..., : cfg.kv_lora_rank])
-    k_rope_new = kv[..., cfg.kv_lora_rank:]
-
-    if cache is not None:
-        pos = cache["pos"]
-        positions = pos + jnp.arange(S)
-        k_rope_new = L.apply_rope(k_rope_new[:, :, None, :], positions,
-                                  cfg.rope_theta)[:, :, 0, :]
-        cc = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), pos, axis=1)
-        cr = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype), pos, axis=1)
-        q_nope, q_rope = _project_q(p, cfg, x, positions)
-        ctx = _attend(cfg, q_nope, q_rope, cc, cr, p,
-                      q_positions=positions, kv_len=pos + S)
-        new_cache = {"c_kv": cc, "k_rope": cr, "pos": pos + S}
-    else:
-        if positions is None:
-            positions = jnp.arange(S)
-        k_rope_new = L.apply_rope(k_rope_new[:, :, None, :], positions,
-                                  cfg.rope_theta)[:, :, 0, :]
-        q_nope, q_rope = _project_q(p, cfg, x, positions)
-        ctx = _attend(cfg, q_nope, q_rope, c_kv, k_rope_new, p,
-                      q_positions=positions)
-        new_cache = None
-    return L.dense(p["wo"], ctx), new_cache
+    if positions is None:
+        positions = jnp.arange(S)
+    k_rope = L.apply_rope(kv[..., cfg.kv_lora_rank:][:, :, None, :],
+                          positions, cfg.rope_theta)[:, :, 0, :]
+    q_nope, q_rope = _project_q(p, cfg, x, positions)
+    ctx = _attend(cfg, q_nope, q_rope, c_kv, k_rope, p,
+                  q_positions=positions)
+    return L.dense(p["wo"], ctx)

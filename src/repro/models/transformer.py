@@ -7,7 +7,7 @@ whose body applies several block kinds in order.
 
 Entry points:
   init_lm(key, arch)                          -> params
-  init_cache(arch, batch, max_len, dtype)     -> cache
+  init_paged_cache(arch, num_blocks, block_size, dtype, slots=...) -> pools
   lm_apply(params, arch, tokens, ...)         -> LMOutput(logits, cache, aux, hidden)
 """
 from __future__ import annotations
@@ -101,22 +101,6 @@ def _init_segment(key, blocks: tuple, repeat: int, arch: ArchConfig, dt) -> Para
     return jax.vmap(one)(jax.random.split(key, repeat))
 
 
-def init_cache(arch: ArchConfig, batch: int, max_len: int,
-               dtype=jnp.bfloat16) -> list:
-    """Per-segment stacked caches (leading repeat axis; the carry of the
-    segment's layer scan, see _apply_segment)."""
-    caches = []
-    for seg in arch.pattern:
-        def one(_):
-            return {f"b{i}": B.init_block_cache(kind, arch, batch, max_len, dtype)
-                    for i, kind in enumerate(seg.blocks)}
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *[one(r) for r in range(seg.repeat)]) \
-            if seg.repeat > 1 else jax.tree.map(lambda x: x[None], one(0))
-        caches.append(stacked)
-    return caches
-
-
 def init_paged_cache(arch: ArchConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16, *, slots: int = 0) -> list:
     """Per-segment stacked serving cache pools (leading repeat axis).
@@ -156,7 +140,7 @@ def encode_frontend(params: Params, arch: ArchConfig, frontend: Array, *,
                     act_sharding=None) -> Array:
     """Run the fixed-length encoder stack over precomputed frame embeddings
     (B, enc_len, d_model) -> encoder output (B, enc_len, d_model).  Shared
-    by the training/wave forward (lm_apply's audio branch) and by serving
+    by the training forward (lm_apply's audio branch) and by serving
     admission (admit_slot runs it ONCE per request, never per step)."""
     cdt = _compute_dtype(arch)
     enc = frontend.astype(cdt)
@@ -199,13 +183,11 @@ def admit_slot(params: Params, arch: ArchConfig, pools: list, slot_id,
     starts from a clean h0).  cross_attn rows are zeroed, or — when the
     admitted request carries ``frontend`` patch embeddings (1, T, d_model) —
     filled with the cross K/V projections computed *once* here, never again
-    per step (the wave Server recomputes nothing either: it serves zero
-    cross K/V, which the zeroed path reproduces exactly).  wdec rows get
-    the encoder cross K/V: ``frontend`` frame embeddings (1, enc_len,
-    d_model) run through the encoder stack once, then every decoder layer's
-    cross projections are written into this slot's rows; without a frontend
-    the rows are zeroed (matching the wave Server, which never filled its
-    cross cache)."""
+    per step.  wdec rows get the encoder cross K/V: ``frontend`` frame
+    embeddings (1, enc_len, d_model) run through the encoder stack once,
+    then every decoder layer's cross projections are written into this
+    slot's rows.  Without a frontend the rows are zeroed: the request is
+    served with zero cross K/V, as the greedy goldens were recorded."""
     cdt = _compute_dtype(arch)
     enc_out = None
     if frontend is not None and \
@@ -337,12 +319,13 @@ def lm_apply(params: Params, arch: ArchConfig, tokens: Optional[Array] = None, *
     """Forward pass.
 
     tokens: (B, S) int32 — LM/decoder tokens (None for pure-frontend encoders).
-    cache:  per-segment stacked caches; None => training forward.
+    cache:  per-segment stacked serving pools (init_paged_cache); None =>
+       training forward.
     frontend: precomputed modality embeddings —
        vlm:   (B, n_img_tokens, d_model) patch embeddings -> cross-attn input
        audio: (B, enc_len, d_model) frame embeddings -> encoder input
-    block_tables: (B, max_blocks) int32 — marks ``cache`` as paged block
-       pools (init_paged_cache); requires per-sequence ``positions`` (B,).
+    block_tables: (B, max_blocks) int32 — the rows' pages in the block
+       pools; required with ``cache``, with per-sequence ``positions`` (B,).
        ``new_lens`` (B,) marks token rows past it as padding (fixed-shape
        prompt chunks; see layers.paged_attention).
     slot_ids: (B,) int32 — pool rows for the slot-indexed state pools
@@ -364,14 +347,11 @@ def lm_apply(params: Params, arch: ArchConfig, tokens: Optional[Array] = None, *
     if arch.encoder is not None:   # whisper decoder: absolute sinusoidal positions
         if cache is None:
             pe = sinusoidal_positions(x.shape[1], arch.d_model)
-        elif block_tables is not None:
-            # paged serving: each batch row decodes at its own absolute
+        else:
+            # serving: each batch row decodes at its own absolute
             # position, so the PE is per-row (B, S, D)
             pe = jax.vmap(lambda p0: sinusoidal_at(
                 p0 + jnp.arange(x.shape[1]), arch.d_model))(positions)
-        else:  # decode: offset from the first wdec self-attn cache position
-            pos0 = cache[0]["b0"]["self"]["pos"][0]
-            pe = sinusoidal_at(pos0 + jnp.arange(x.shape[1]), arch.d_model)
         x = x + pe.astype(cdt)
 
     if positions is None and cache is None:

@@ -1,6 +1,7 @@
 """Step factories: train_step (grad-accum microbatching, remat, clipping,
-optimizer), prefill_step, decode_step.  These are what the launcher jits with
-the ASA plan's in/out shardings and what the dry-run lowers."""
+optimizer) and the serving engine's paged prefill, paged decode and slot
+admission.  These are what the launcher and the engine jit with the ASA
+plan's shardings and what the dry-run lowers."""
 from __future__ import annotations
 
 import jax
@@ -20,12 +21,10 @@ from repro.optim import optimizers as O
 # or donating a buffer some caller still holds.
 #
 #   train:         (params, opt_state) are consumed and returned updated
-#   prefill/decode + paged/slot variants: the cache is the mutable carry;
+#   paged prefill/decode, slot admission: the cache is the mutable carry;
 #                  params are read-only weights and must NOT be donated
 STEP_DONATION: dict[str, tuple[int, ...]] = {
     "train": (0, 1),
-    "prefill": (1,),
-    "decode": (1,),
     "paged_prefill": (1,),
     "paged_decode": (1,),
     "slot_admit": (1,),
@@ -123,27 +122,6 @@ def make_train_step(arch: ArchConfig, optimizer, *, microbatches: int = 1,
         return params, opt_state, metrics
 
     return train_step
-
-
-def make_prefill_step(arch: ArchConfig, *, impl: str = "xla",
-                      act_sharding=None):
-    """-> prefill(params, cache, tokens[, frontend]) -> (last_logits, cache)."""
-    def prefill_step(params, cache, tokens, frontend=None):
-        out = T.lm_apply(params, arch, tokens, cache=cache,
-                         frontend=frontend, impl=impl,
-                         act_sharding=act_sharding)
-        return out.logits[:, -1], out.cache
-    return prefill_step
-
-
-def make_decode_step(arch: ArchConfig, *, impl: str = "xla",
-                     act_sharding=None):
-    """-> decode(params, cache, tokens (B,1)) -> (logits (B,V), cache)."""
-    def decode_step(params, cache, tokens):
-        out = T.lm_apply(params, arch, tokens, cache=cache, impl=impl,
-                         act_sharding=act_sharding)
-        return out.logits[:, -1], out.cache
-    return decode_step
 
 
 # --- paged steps (continuous-batching engine, repro/serving/) --------------

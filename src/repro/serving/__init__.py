@@ -94,9 +94,8 @@ Layers (bottom up):
                     repro.launch.serve_cluster).  Imported explicitly as
                     ``repro.serving.cluster`` — not re-exported here.
 
-The wave-synchronized Server was retired: runtime/server.py is now a thin
-deprecation shim that delegates to this engine (greedy parity with the
-pre-shim wave implementation is pinned in tests/goldens_serving.json).
+The engine is the only serving API.  Its greedy outputs are pinned in
+tests/goldens_serving.json, frozen from the retired wave Server.
 """
 from repro.serving.cache_manager import (PAGEABLE_KINDS, SLOT_STATE_KINDS,
                                          UnifiedCacheManager)

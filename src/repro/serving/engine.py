@@ -1,8 +1,7 @@
 """Continuous-batching engine: per-slot decode positions over a unified
 serving cache (paged KV / latent block pools + slot-indexed state pools),
 admission into freed slots every step, chunked prefill interleaved with
-decode.  This is the ONLY decode path — the wave-synchronized Server was
-retired to a compatibility shim delegating here (runtime/server.py).
+decode.  This is the ONLY decode path and the only serving API.
 
 Generation API v2: requests and results are two typed objects.
 
@@ -92,7 +91,7 @@ paged attention paths mask exactly the same prefix (layers._paged_sdpa,
 mla.mla_paged_attention), the slot-state path runs the same recurrence on
 gathered rows, and temperature=0 sampling is a bare argmax inside the
 fused sampler.  tests/test_serving.py pins this against golden token
-sequences frozen from the pre-shim wave implementation, for every arch
+sequences frozen from the retired wave Server, for every arch
 family, including under forced preemption and on a multi-host (data=4,
 model=2) mesh.
 """

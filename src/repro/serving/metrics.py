@@ -332,32 +332,3 @@ class ServingMetrics:
         previous report intact, never truncated JSON next to bench
         results."""
         atomic_write_text(path, self.to_json(**extra) + "\n")
-
-    # -- benchmark support --------------------------------------------------
-    def adopt_step_stats(self, other: "ServingMetrics") -> None:
-        """Take over another collector's engine-level step statistics
-        (histograms, windows, counters, phase/step timing) while keeping
-        this collector's request lifecycle dicts.  serve_bench uses this
-        to rebuild TTFT/TPOT from trace *arrival* times without losing the
-        real run's measured engine counters."""
-        self.telemetry = other.telemetry
-        self.queue_depth = other.queue_depth
-        self.slot_occupancy = other.slot_occupancy
-        self.block_utilization = other.block_utilization
-        self.step_time = other.step_time
-        self.phase = other.phase
-        self._win_queue = other._win_queue
-        self._win_occupancy = other._win_occupancy
-        self._win_util = other._win_util
-        self._win_hit = other._win_hit
-        self._win_lookup = other._win_lookup
-        self.compiles = other.compiles
-        self.preemptions = other.preemptions
-        self.engine_steps = other.engine_steps
-        self.prefill_chunks = other.prefill_chunks
-        self.decode_steps = other.decode_steps
-        self.finish_reasons = dict(other.finish_reasons)
-        self.prefix_hit_tokens = other.prefix_hit_tokens
-        self.prefix_lookup_tokens = other.prefix_lookup_tokens
-        self.scheduler_stats = other.scheduler_stats
-        self.cache_stats = other.cache_stats

@@ -1,17 +1,15 @@
 """Golden generator for the serving parity suite.
 
-Run ONCE against the pre-shim wave Server (commit 17a83e0's
-runtime/server.py — batched full-cache prefill + lock-step decode waves) to
-freeze its greedy outputs for every scenario in serving_fixtures.SCENARIOS:
+The checked-in goldens_serving.json was frozen from the retired wave Server
+(commit 17a83e0's runtime/server.py — batched full-cache prefill +
+lock-step decode waves): its greedy outputs for every scenario in
+serving_fixtures.SCENARIOS.  The continuous engine's parity tests compare
+against that pinned JSON.  This script drives ContinuousBatchingEngine over
+the same scenarios, so its output would be the engine's own tokens and a
+comparison against it circular: it refuses to overwrite an existing
+goldens file, and writes one only where none is checked in.
 
     PYTHONPATH=src:tests python tests/gen_serving_goldens.py
-
-The continuous engine's parity tests then compare against the pinned JSON,
-NOT against a live wave run — after the wave Server became a shim over the
-engine, a live comparison would be circular.  Do not regenerate this file
-from a post-shim checkout (it would capture the engine's own outputs and
-silently erase the baseline); the checked-in goldens_serving.json is the
-falsifiable artifact.
 
 The goldens are greedy-only and stay that way under the v2 generation API:
 the default ``SamplingParams()`` is temperature-0 argmax, so every parity
@@ -23,8 +21,8 @@ recompute-preemption), pinned by the sampling tests in test_serving.py.
 
 Where the no-cache forward has identical semantics (attention-only, SSM,
 hybrid, shared-block and MLA configs), the script also greedy-decodes each
-request with plain full-context ``lm_apply`` calls and asserts the wave
-Server matched that independent oracle.  (Cross-attn / enc-dec configs are
+request with plain full-context ``lm_apply`` calls and asserts the engine
+matches that independent oracle.  (Cross-attn / enc-dec configs are
 excluded from the oracle: without a cache the cross-attention falls back to
 self-attention, which is not what serving-with-zero-cross-K/V computes.)
 """
@@ -40,13 +38,11 @@ sys.path.insert(0, str(ROOT / "tests"))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
-from repro.runtime.server import Request as WaveRequest, Server
-from serving_fixtures import (GOLDENS_PATH, SCENARIOS, ARCH_BY_KEY,
-                              scenario_requests)
+from repro.serving import ContinuousBatchingEngine, Request
+from serving_fixtures import GOLDENS_PATH, SCENARIOS, scenario_requests
 
 # configs whose cache-free forward equals the serving computation (oracle)
 ORACLE_OK = {"tiny", "ssm", "hybrid", "shared", "mla"}
@@ -65,6 +61,13 @@ def reference_decode(params, arch, prompt, n_new: int) -> list[int]:
 
 
 def main():
+    if GOLDENS_PATH.exists():
+        sys.exit(
+            f"REFUSING to regenerate goldens: {GOLDENS_PATH} holds the "
+            f"retired wave Server's outputs (captured at 17a83e0), the "
+            f"baseline every parity test compares the engine with; "
+            f"overwriting it with the engine's own tokens would make them "
+            f"circular.")
     mesh = make_host_mesh()
     params_cache = {}
     scenarios_out = {}
@@ -79,36 +82,30 @@ def main():
                                                     arch)
         params = params_cache[arch.name]
 
-        srv = Server(arch, params, mesh, slots=slots, max_len=max_len)
-        if hasattr(srv, "engine"):
-            sys.exit(
-                "REFUSING to regenerate goldens: this checkout's Server is "
-                "the post-shim delegate to ContinuousBatchingEngine, so the "
-                "output would be the engine's own tokens and every parity "
-                "test would become circular.  The checked-in "
-                "goldens_serving.json (captured at 17a83e0) is the "
-                "baseline; do not overwrite it.")
+        eng = ContinuousBatchingEngine(arch, params, mesh, slots=slots,
+                                       max_len=max_len)
         for rid, prompt, max_new in reqs:
-            srv.submit(WaveRequest(id=rid, prompt=prompt.copy(),
-                                   max_new_tokens=max_new))
-        srv.run_until_drained()
-        wave = {r.id: list(map(int, r.out_tokens)) for r in srv.completed}
+            eng.submit(Request(id=rid, prompt=prompt.copy(),
+                               max_new_tokens=max_new))
+        eng.run_until_drained()
+        got = {o.request_id: list(map(int, o.token_ids))
+               for o in eng.completed}
 
         key = SCENARIOS[name]["arch"]
         if key in ORACLE_OK:
             for rid, prompt, max_new in reqs:
-                n_new = len(wave[rid])
+                n_new = len(got[rid])
                 ref = reference_decode(params, arch, prompt, n_new)
-                assert wave[rid] == ref, (
-                    f"{name} req {rid}: wave {wave[rid]} != oracle {ref}")
-        scenarios_out[name] = {str(k): v for k, v in sorted(wave.items())}
+                assert got[rid] == ref, (
+                    f"{name} req {rid}: engine {got[rid]} != oracle {ref}")
+        scenarios_out[name] = {str(k): v for k, v in sorted(got.items())}
         print(f"{name}: {[len(v) for v in scenarios_out[name].values()]} "
               f"tokens per request")
 
     data = {
         "_meta": {
-            "source": "pre-shim wave Server (runtime/server.py @ 17a83e0): "
-                      "batched full-cache prefill + lock-step decode waves",
+            "source": "ContinuousBatchingEngine (tests/"
+                      "gen_serving_goldens.py)",
             "params": "T.init_lm(jax.random.PRNGKey(0), arch), float32",
             "oracle_checked": sorted(ORACLE_OK),
         },

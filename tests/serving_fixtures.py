@@ -22,7 +22,7 @@ accept that; the parity suite must not.
 
 Each SCENARIOS entry pins the request set (prompts, per-request max_new,
 slots, max_len) whose greedy outputs are frozen in goldens_serving.json —
-captured from the pre-shim wave Server (see gen_serving_goldens.py).
+captured from the retired wave Server (see gen_serving_goldens.py).
 """
 from __future__ import annotations
 

@@ -61,7 +61,7 @@ import numpy as np
 def helper(x):
     return np.asarray(x)
 
-def make_decode_step(arch):
+def make_paged_decode_step(arch):
     def step(params, tok):
         return helper(tok)
     return step
@@ -76,7 +76,7 @@ def test_jit_host_sync_good():
     src = """
 import jax.numpy as jnp
 
-def make_decode_step(arch):
+def make_paged_decode_step(arch):
     def step(params, tok):
         return jnp.sum(tok)
     return step

@@ -58,12 +58,18 @@ def test_train_step_smoke(name):
 def test_decode_step_smoke(name):
     arch = reduce_for_smoke(ARCHS[name])
     params = T.init_lm(jax.random.PRNGKey(0), arch)
-    B = 2
-    cache = T.init_cache(arch, B, 24, jnp.float32)
-    pre = ST.make_prefill_step(arch)
-    dec = ST.make_decode_step(arch)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (B, 8), 0, arch.vocab)
-    logits, cache = jax.jit(pre)(params, cache, toks)
+    B, S, BS, MB = 2, 8, 4, 6          # 6 blocks of 4: 24 tokens a row
+    cache = T.init_paged_cache(arch, B * MB + 1, BS, jnp.float32, slots=B)
+    tables = 1 + jnp.arange(B * MB, dtype=jnp.int32).reshape(B, MB)
+    slot_ids = jnp.arange(B, dtype=jnp.int32)
+    pre = ST.make_paged_prefill_step(arch)
+    dec = ST.make_paged_decode_step(arch)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, arch.vocab)
+    logits, cache = jax.jit(pre)(params, cache, toks,
+                                 jnp.zeros((B,), jnp.int32), tables,
+                                 jnp.full((B,), S, jnp.int32), slot_ids)
     assert logits.shape == (B, arch.padded_vocab)
-    logits2, cache = jax.jit(dec)(params, cache, toks[:, :1])
+    logits2, cache = jax.jit(dec)(params, cache, toks[:, :1],
+                                  jnp.full((B,), S, jnp.int32), tables,
+                                  slot_ids)
     assert not np.any(np.isnan(np.asarray(logits2)))

@@ -1,10 +1,10 @@
-"""Grouped-query contraction on the paged attention path.
+"""Grouped-query contraction in attention.
 
-``layers._paged_sdpa`` contracts each KV head with its own group of query
-heads instead of broadcasting the gathered K/V to every query head.  These
-tests hold ``paged_attention`` to the broadcast form it replaced (kept here
-as the reference), and check that a lowered GQA paged decode step no longer
-carries a K/V broadcast to n_heads.
+``layers._paged_sdpa`` and ``layers._sdpa`` contract each KV head with its
+own group of query heads instead of broadcasting K/V to every query head.
+These tests hold ``paged_attention`` and ``_sdpa`` to the broadcast form
+they replaced (kept here as the reference), and check that a lowered GQA
+paged decode step no longer carries a K/V broadcast to n_heads.
 """
 from __future__ import annotations
 
@@ -23,11 +23,16 @@ from serving_fixtures import TINY
 N_KV, HEAD_DIM, BLOCK, N_BLOCKS = 2, 16, 4, 16
 
 
+def _broadcast_heads(t, n_heads):
+    """(B,T,Hkv,D) -> (B,T,H,D): each kv head copied to its query group."""
+    return jnp.repeat(t, n_heads // t.shape[2], axis=2)
+
+
 def _broadcast_sdpa(q, k, v, *, scale, q_pos, kv_len):
     """The paged SDPA before grouping: K/V broadcast to all H query heads,
     then one head-wise contraction."""
     B, S, H, D = q.shape
-    k, v = L._expand_kv(k, H), L._expand_kv(v, H)
+    k, v = _broadcast_heads(k, H), _broadcast_heads(v, H)
     T_ = k.shape[1]
     logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
     kp = jnp.arange(T_)
@@ -87,6 +92,44 @@ def test_grouped_paged_attention_matches_broadcast_form(group, mode, dtype,
         np.testing.assert_array_equal(got, want)
     else:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _broadcast_dense_sdpa(q, k, v, *, causal, scale):
+    """``_sdpa`` before grouping: K/V broadcast to all H query heads, one
+    head-wise contraction over the whole (S, T) logits."""
+    H = q.shape[2]
+    k, v = _broadcast_heads(k, H), _broadcast_heads(v, H)
+    S, T_ = q.shape[1], k.shape[1]
+    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
+    if causal:
+        logits = jnp.where(jnp.arange(S)[:, None] >= jnp.arange(T_)[None, :],
+                           logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhst,bthd->bshd", probs, v)
+
+
+# dense: a short sequence; chunked: one past SDPA_CHUNK_THRESHOLD**2 logits,
+# not a multiple of SDPA_CHUNK, so the last query block is padded
+SDPA_SEQ = {"dense": 24, "chunked": L.SDPA_CHUNK_THRESHOLD + 16}
+
+
+@pytest.mark.parametrize("form", sorted(SDPA_SEQ))
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_grouped_sdpa_matches_broadcast_form(group, form):
+    S = SDPA_SEQ[form]
+    assert (S * S > L.SDPA_CHUNK_THRESHOLD ** 2 and S > L.SDPA_CHUNK) \
+        == (form == "chunked")
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (2, S, N_KV * group, HEAD_DIM))
+    k = jax.random.normal(kk, (2, S, N_KV, HEAD_DIM))
+    v = jax.random.normal(kv, (2, S, N_KV, HEAD_DIM))
+    for causal in (True, False):
+        got = jax.jit(lambda q, k, v: L._sdpa(
+            q, k, v, causal=causal, scale=0.25))(q, k, v)
+        want = _broadcast_dense_sdpa(q, k, v, causal=causal, scale=0.25)
+        assert got.shape == want.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def _eqns(jaxpr):

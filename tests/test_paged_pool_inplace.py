@@ -3,9 +3,9 @@
 ``transformer._apply_segment`` carries a segment's stacked cache through its
 layer scan and each layer writes only its new rows at its own index
 (``layers.paged_write_gather``, ``layers.layer_index``).  These tests hold
-the paged and contiguous steps to the formulation this replaced, kept here
-as the reference: each layer's cache a scanned input, written as a whole
-and stacked again as the scan's output.  They also check that the compiled
+the paged steps to the formulation this replaced, kept here as the
+reference: each layer's cache a scanned input, written as a whole and
+stacked again as the scan's output.  They also check that the compiled
 paged decode holds no copy, slice or write-back of a whole pool.
 """
 from __future__ import annotations
@@ -99,18 +99,6 @@ def _paged_run(arch, mode, pool_dtype):
     return jax.jit(step)(params, pools, *args)
 
 
-def _contiguous_run(arch):
-    """-> (prefill logits, decode logits, caches) of the contiguous-cache
-    prefill and one decode on random weights and caches."""
-    params = T.init_lm(jax.random.PRNGKey(0), arch)
-    cache = _randomized(T.init_cache(arch, 2, 16, jnp.float32), 1)
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 5), 0, arch.vocab)
-    lp, cache = jax.jit(ST.make_prefill_step(arch))(params, cache, tokens)
-    ld, cache = jax.jit(ST.make_decode_step(arch))(params, cache,
-                                                   tokens[:, -1:])
-    return (lp, ld), cache
-
-
 def _assert_same(got, want):
     (g_logits, g_cache), (w_logits, w_cache) = got, want
     assert jax.tree.structure(g_cache) == jax.tree.structure(w_cache)
@@ -144,14 +132,6 @@ def test_slot_state_paged_step_matches_per_layer_formulation(key, mode,
     got = _paged_run(arch, mode, jnp.float32)
     monkeypatch.setattr(T, "_apply_segment", _reference_apply_segment)
     _assert_same(got, _paged_run(arch, mode, jnp.float32))
-
-
-@pytest.mark.parametrize("key", sorted(ARCH_BY_KEY))
-def test_contiguous_steps_match_per_layer_formulation(key, monkeypatch):
-    arch = ARCH_BY_KEY[key]
-    got = _contiguous_run(arch)
-    monkeypatch.setattr(T, "_apply_segment", _reference_apply_segment)
-    _assert_same(got, _contiguous_run(arch))
 
 
 # --- structure ---------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Trainer / Server / monitor runtime tests (single-device CPU mesh)."""
+"""Trainer / serving engine / monitor runtime tests (single-device CPU
+mesh)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,8 +9,8 @@ from repro.core.profiler import StepMonitor
 from repro.data import SyntheticLM
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
-from repro.runtime.server import Request, Server
 from repro.runtime.trainer import TrainConfig, Trainer
+from repro.serving import ContinuousBatchingEngine, Request
 
 TINY = ArchConfig(name="tiny-rt", family="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab=256,
@@ -62,18 +63,19 @@ def test_step_monitor_triggers_on_drift():
 def test_server_greedy_decode_matches_reference():
     mesh = make_host_mesh()
     params = T.init_lm(jax.random.PRNGKey(0), TINY)
-    srv = Server(TINY, params, mesh, slots=2, max_len=64)
+    eng = ContinuousBatchingEngine(TINY, params, mesh, slots=2, max_len=64)
     prompts = [np.arange(1, 9, dtype=np.int32) + i for i in range(3)]
     for i, p in enumerate(prompts):
-        srv.submit(Request(id=i, prompt=p, max_new_tokens=4))
-    srv.run_until_drained()
-    assert len(srv.completed) == 3
+        eng.submit(Request(id=i, prompt=p, max_new_tokens=4))
+    eng.run_until_drained()
+    assert len(eng.completed) == 3
     # reference greedy decode with plain forward passes
-    for req in srv.completed:
-        ctx = list(req.prompt)
-        for tok in req.out_tokens:
+    for out in eng.completed:
+        assert len(out.token_ids) == 4
+        ctx = list(prompts[out.request_id])
+        for tok in out.token_ids:
             logits = T.lm_apply(params, TINY,
                                 jnp.asarray([ctx], jnp.int32)).logits
             expect = int(jnp.argmax(logits[0, -1, : TINY.vocab]))
-            assert tok == expect, (req.id, ctx, tok, expect)
+            assert tok == expect, (out.request_id, ctx, tok, expect)
             ctx.append(tok)

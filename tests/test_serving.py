@@ -7,10 +7,9 @@ pure-SSM, hybrid, cross-attention, zamba2's weight-shared block, whisper's
 encoder-decoder and MLA latent attention.
 
 Greedy parity is asserted against tests/goldens_serving.json — token
-sequences frozen from the pre-shim wave Server (see
-gen_serving_goldens.py).  The wave Server is now a compatibility shim over
-the engine, so a live comparison would be circular; the pinned goldens
-keep parity falsifiable.  Stochastic decode has no goldens: its contract
+sequences frozen from the retired wave Server (see gen_serving_goldens.py).
+The pinned goldens keep parity falsifiable: the engine is the only serving
+path left, so a live comparison would be circular.  Stochastic decode has no goldens: its contract
 is determinism — bit-identical reruns, seed sensitivity, and invariance
 under forced recompute-preemption — which the sampling tests pin instead.
 """
@@ -496,7 +495,7 @@ def test_preemption_victim_ranks_by_resident_footprint():
 
 
 # ---------------------------------------------------------------------------
-# engine: greedy parity against the pre-shim wave goldens
+# engine: greedy parity against the wave-era goldens
 # ---------------------------------------------------------------------------
 
 # every arch family, with chunked prefill (chunk < prompt) and slot churn
@@ -789,8 +788,7 @@ def test_submit_rejects_duplicate_ids_and_empty_prompts():
 def test_submit_rejects_zero_max_new_tokens():
     """Regression: a max_new_tokens=0 request still generated one token —
     the prefill path unconditionally samples after the final chunk.  Policy:
-    reject at submit (consistently enforced for the Server shim too, which
-    delegates here)."""
+    reject at submit."""
     mesh = make_host_mesh()
     eng = ContinuousBatchingEngine(TINY, _params_for(TINY), mesh, slots=2,
                                    max_len=64, block_size=4, prefill_chunk=8)
@@ -957,54 +955,6 @@ def test_multihost_parity_under_preemption():
     assert {o.request_id: o.token_ids for o in eng.completed} \
         == load_goldens("hybrid/preempt")
     assert eng.metrics.preemptions > 0
-
-
-# ---------------------------------------------------------------------------
-# the wave Server compatibility shim
-# ---------------------------------------------------------------------------
-
-def test_server_shim_delegates_to_engine():
-    """runtime.server.Server is a deprecation shim: same API, every token
-    now decoded by the continuous engine — outputs must match the pinned
-    pre-shim wave goldens."""
-    from repro.runtime.server import Request as WaveRequest, Server
-    mesh = make_host_mesh()
-    arch, reqs, slots, max_len = scenario_requests("tiny/base")
-    with pytest.deprecated_call():
-        srv = Server(arch, _params_for(arch), mesh, slots=slots,
-                     max_len=max_len)
-    legacy = [WaveRequest(id=rid, prompt=p.copy(), max_new_tokens=mn)
-              for rid, p, mn in reqs]
-    for r in legacy:
-        srv.submit(r)
-    srv.run_until_drained()
-    got = {r.id: r.out_tokens for r in srv.completed}
-    assert got == load_goldens("tiny/base")
-    assert all(r.done for r in legacy)        # caller's objects mutated
-    assert srv.decode_steps > 0
-    # the engine's validation applies through the shim
-    with pytest.raises(ValueError, match="max_new_tokens"):
-        srv.submit(WaveRequest(id=99,
-                               prompt=np.arange(1, 5, dtype=np.int32),
-                               max_new_tokens=0))
-
-
-def test_server_shim_serves_formerly_excluded_archs():
-    """zamba2-shaped and whisper-shaped configs now serve through the shim
-    (they were the wave path's last reason to exist)."""
-    from repro.runtime.server import Request as WaveRequest, Server
-    mesh = make_host_mesh()
-    for scenario in ("shared/base", "encdec/base"):
-        arch, reqs, slots, max_len = scenario_requests(scenario)
-        with pytest.deprecated_call():
-            srv = Server(arch, _params_for(arch), mesh, slots=slots,
-                         max_len=max_len)
-        for rid, p, mn in reqs:
-            srv.submit(WaveRequest(id=rid, prompt=p.copy(),
-                                   max_new_tokens=mn))
-        srv.run_until_drained()
-        got = {r.id: r.out_tokens for r in srv.completed}
-        assert got == load_goldens(scenario), scenario
 
 
 # ---------------------------------------------------------------------------

@@ -57,8 +57,8 @@ def test_cache_specs_mirror_cache_tree(name):
     assignment = {c.name: Strategy.MP for c in comps}
     mesh = MeshShape(16, 16)
     cache_sds = jax.eval_shape(
-        lambda: T.init_cache(arch, 128, 256, jnp.bfloat16))
-    specs = SH.cache_specs(arch, assignment, mesh, 128)
+        lambda: T.init_paged_cache(arch, 1025, 16, jnp.bfloat16, slots=128))
+    specs = SH.paged_cache_specs(arch, assignment, mesh)
     assert jax.tree.structure(specs, is_leaf=lambda x: isinstance(x, P)) \
         == jax.tree.structure(cache_sds)
     for leaf, spec in zip(jax.tree.leaves(cache_sds),

@@ -94,23 +94,37 @@ def test_checkpoint_restart_exact(tmp_path):
     assert np.array_equal(b_next["tokens"], b_orig["tokens"])
 
 
-def test_decode_matches_forward_all_families():
-    for name in ("qwen3-8b", "mamba2-780m", "zamba2-2.7b",
-                 "deepseek-v3-671b", "whisper-medium"):
-        arch = reduce_for_smoke(ARCHS[name])
-        params = T.init_lm(jax.random.PRNGKey(2), arch)
-        B, S = 2, 12
-        toks = jax.random.randint(jax.random.PRNGKey(3), (B, S + 1), 0,
-                                  arch.vocab)
-        fe = None
-        if arch.frontend == "vision":
-            fe = jnp.ones((B, arch.n_img_tokens, arch.d_model))
-        elif arch.frontend == "audio":
-            fe = jnp.ones((B, arch.encoder.seq_len, arch.d_model))
-        full = T.lm_apply(params, arch, toks, frontend=fe)
-        cache = T.init_cache(arch, B, 32, jnp.float32)
-        pre = T.lm_apply(params, arch, toks[:, :S], cache=cache, frontend=fe)
-        dec = T.lm_apply(params, arch, toks[:, S:], cache=pre.cache)
-        a, b = np.asarray(full.logits[:, S]), np.asarray(dec.logits[:, 0])
-        rel = np.max(np.abs(a - b)) / (np.max(np.abs(a)) + 1e-9)
-        assert rel < 5e-3, (name, rel)
+@pytest.mark.parametrize("name", ["qwen3-8b", "mamba2-780m", "zamba2-2.7b",
+                                  "deepseek-v3-671b", "whisper-medium"])
+def test_decode_matches_forward_all_families(name):
+    """The engine's steps against the cache-free forward: each row's slot
+    admitted with its frontend, the S prompt tokens prefilled as one
+    chunk, then one token decoded at position S."""
+    arch = reduce_for_smoke(ARCHS[name])
+    params = T.init_lm(jax.random.PRNGKey(2), arch)
+    B, S, BS, MB = 2, 12, 4, 8
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, S + 1), 0,
+                              arch.vocab)
+    fe = None
+    if arch.frontend == "vision":
+        fe = jnp.ones((B, arch.n_img_tokens, arch.d_model))
+    elif arch.frontend == "audio":
+        fe = jnp.ones((B, arch.encoder.seq_len, arch.d_model))
+    full = T.lm_apply(params, arch, toks, frontend=fe)
+
+    cache = T.init_paged_cache(arch, B * MB + 1, BS, jnp.float32, slots=B)
+    tables = 1 + jnp.arange(B * MB, dtype=jnp.int32).reshape(B, MB)
+    slot_ids = jnp.arange(B, dtype=jnp.int32)
+    admit = jax.jit(ST.make_slot_admit_step(arch))
+    for b in range(B):
+        cache = admit(params, cache, jnp.int32(b),
+                      None if fe is None else fe[b:b + 1])
+    _, cache = jax.jit(ST.make_paged_prefill_step(arch))(
+        params, cache, toks[:, :S], jnp.zeros((B,), jnp.int32), tables,
+        jnp.full((B,), S, jnp.int32), slot_ids)
+    dec, _ = jax.jit(ST.make_paged_decode_step(arch))(
+        params, cache, toks[:, S:], jnp.full((B,), S, jnp.int32), tables,
+        slot_ids)
+    a, b = np.asarray(full.logits[:, S]), np.asarray(dec)
+    rel = np.max(np.abs(a - b)) / (np.max(np.abs(a)) + 1e-9)
+    assert rel < 5e-3, (name, rel)

@@ -261,9 +261,8 @@ def test_jit_step_owns_donation():
 
 
 def test_step_donation_covers_every_kind():
-    assert set(ST.STEP_DONATION) == {"train", "prefill", "decode",
-                                     "paged_prefill", "paged_decode",
-                                     "slot_admit"}
+    assert set(ST.STEP_DONATION) == {"train", "paged_prefill",
+                                     "paged_decode", "slot_admit"}
     # params are never donated outside training
     for kind, argnums in ST.STEP_DONATION.items():
         if kind != "train":
